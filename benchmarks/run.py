@@ -6,7 +6,7 @@ writes BENCH_sketch.json (updates/sec for the scan / chunked /
 engine-buffered paths + COMBINE latency vs k, plus the per-strategy
 reduction latencies folded in from the scaling sweep); the ``scaling``
 section runs the StreamRuntime scaling study (repro.launch.scale, in a
-subprocess so it can force multiple host devices) and writes
+child process so it can force multiple host devices on the CPU) and writes
 BENCH_scaling.json; the ``plan`` section runs the autotuner probe sweep
 (repro.launch.tune --quick, also subprocess-bootstrapped) into
 BENCH_plan.json and times the PlanService ``plan_resolution`` hot path;
@@ -26,7 +26,10 @@ latency percentiles.
 when --only is not given, restricts the run to just those two sections);
 ``--check`` gates the run: fused must be bitwise-identical to the unfused
 paths across the state matrix, and no planned impl may regress the
-measured best beyond tolerance — non-zero exit on failure.
+measured best beyond tolerance — non-zero exit on failure. A child phase
+(scaling, plan, serve) that fails fails the run too. Children inherit the
+caller's environment and run before this process touches JAX, so on a
+chip host each phase holds the chip alone.
 """
 from __future__ import annotations
 
@@ -38,26 +41,37 @@ import sys
 from pathlib import Path
 
 
+def _child(module: str, *args: str) -> bool:
+    """Run one ``python -m <module>`` phase in a child process.
+
+    The child inherits the caller's environment unchanged — it lands on
+    whatever backend the parent would (the chip, where there is one) —
+    and runs while this process has not yet touched JAX, so the two never
+    contend for a device. A non-zero exit is reported and fails the run.
+    """
+    env = dict(os.environ)
+    src = str(Path(__file__).resolve().parent.parent / "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    r = subprocess.run([sys.executable, "-m", module, *args],
+                       capture_output=True, text=True, env=env)
+    if r.returncode != 0:
+        print(f"{module},failed,rc={r.returncode} {r.stderr[-500:]!r}",
+              file=sys.stderr)
+    return r.returncode == 0
+
+
 def run_plan(emit, out_path: str, cache_dir: str) -> dict | None:
     """The autotuner probe sweep via ``repro.launch.tune --quick``.
 
-    Runs in a subprocess for the same reason as the scaling section (the
-    reduction probes force extra host devices); writes BENCH_plan.json and
-    surfaces the chosen plan + check margins in the CSV. The plan is
-    cached into ``cache_dir`` (a bench-private directory, never the
-    user's real plan cache) so ``bench_plan_resolution`` can time
-    resolution of the plan THIS run produced.
+    Runs in a child process (the reduction probes may re-exec with forced
+    host devices); writes BENCH_plan.json and surfaces the chosen plan +
+    check margins in the CSV. The plan is cached into ``cache_dir`` (a
+    bench-private directory, never the user's real plan cache) so
+    ``bench_plan_resolution`` can time resolution of the plan THIS run
+    produced.
     """
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    src = str(Path(__file__).resolve().parent.parent / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    r = subprocess.run(
-        [sys.executable, "-m", "repro.launch.tune", "--quick",
-         "--cache-dir", cache_dir, "--out", out_path],
-        capture_output=True, text=True, env=env)
-    if r.returncode != 0:
-        print(f"plan,failed,{r.stderr[-500:]!r}", file=sys.stderr)
+    if not _child("repro.launch.tune", "--quick", "--cache-dir", cache_dir,
+                  "--out", out_path):
         return None
     record = json.loads(Path(out_path).read_text())
     for op, table in record["plan"]["kernels"].items():
@@ -89,20 +103,11 @@ def bench_plan_resolution(emit, cache_dir: str | None = None) -> dict:
 def run_scaling(emit, out_path: str) -> dict | None:
     """The paper's scaling study via ``repro.launch.scale --quick``.
 
-    Runs in a subprocess because the sweep needs several forced host
-    devices and XLA fixes the device count when the parent's backend
-    initializes; the CLI bootstraps XLA_FLAGS itself.
+    Runs in a child process because on the CPU backend the sweep needs
+    several forced host devices and XLA fixes the device count when a
+    process's backend initializes; the CLI bootstraps XLA_FLAGS itself.
     """
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    src = str(Path(__file__).resolve().parent.parent / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    r = subprocess.run(
-        [sys.executable, "-m", "repro.launch.scale", "--quick",
-         "--out", out_path],
-        capture_output=True, text=True, env=env)
-    if r.returncode != 0:
-        print(f"scaling,failed,{r.stderr[-500:]!r}", file=sys.stderr)
+    if not _child("repro.launch.scale", "--quick", "--out", out_path):
         return None
     record = json.loads(Path(out_path).read_text())
     for cell in record["cells"]:
@@ -119,22 +124,13 @@ def run_scaling(emit, out_path: str) -> dict | None:
 def run_serve(emit, out_path: str) -> dict | None:
     """The serving-tier load harness via ``repro.launch.bench_serve``.
 
-    Runs in a subprocess (its reader threads + ingest thread deserve a
+    Runs in a child process (its reader threads + ingest thread deserve a
     fresh jax process, and the quick profile pins sizes); writes
     BENCH_serve.json and surfaces the headline numbers — sustained
     updates/sec with and without readers, their ratio, and per-op p50/p99
     read latency — in the CSV.
     """
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    src = str(Path(__file__).resolve().parent.parent / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    r = subprocess.run(
-        [sys.executable, "-m", "repro.launch.bench_serve", "--quick",
-         "--out", out_path],
-        capture_output=True, text=True, env=env)
-    if r.returncode != 0:
-        print(f"serve,failed,{r.stderr[-500:]!r}", file=sys.stderr)
+    if not _child("repro.launch.bench_serve", "--quick", "--out", out_path):
         return None
     record = json.loads(Path(out_path).read_text())
     for impl, res in record["impls"].items():
@@ -176,12 +172,33 @@ def main() -> None:
     if args.quick and only is None:
         only = {"sketch", "roofline"}
 
-    from benchmarks import paper_benches as P
-
     print("name,value,derived")
 
     def emit(name, value, derived=""):
         print(f"{name},{value},{derived}", flush=True)
+
+    # the child-process phases run first, while this process has not yet
+    # touched JAX: a process that holds the chip would starve them
+    failed: list[str] = []
+    scaling_record = None
+    scaling_attempted = only is None or "scaling" in only
+    if scaling_attempted:
+        scaling_record = run_scaling(emit, args.scaling_json)
+        if scaling_record is None:
+            failed.append("scaling")
+
+    plan_cache = None
+    if only is None or "plan" in only:
+        import tempfile
+        plan_cache = tempfile.mkdtemp(prefix="bench-plan-cache-")
+        if run_plan(emit, args.plan_json, plan_cache) is None:
+            failed.append("plan")
+
+    if only is None or "serve" in only:
+        if run_serve(emit, args.serve_json) is None:
+            failed.append("serve")
+
+    from benchmarks import paper_benches as P
 
     selected = {
         "fig1": P.fig1_are,
@@ -194,19 +211,8 @@ def main() -> None:
             continue
         fn(emit)
 
-    scaling_record = None
-    scaling_attempted = only is None or "scaling" in only
-    if scaling_attempted:
-        scaling_record = run_scaling(emit, args.scaling_json)
-
-    if only is None or "plan" in only:
-        import tempfile
-        plan_cache = tempfile.mkdtemp(prefix="bench-plan-cache-")
-        run_plan(emit, args.plan_json, plan_cache)
+    if plan_cache is not None:
         bench_plan_resolution(emit, cache_dir=plan_cache)
-
-    if only is None or "serve" in only:
-        run_serve(emit, args.serve_json)
 
     check_failures: list[str] = []
     roofline_record = None
@@ -268,6 +274,9 @@ def main() -> None:
         emit("check", "ok",
              "fused-bitwise-matrix+planned-vs-best" if roofline_record
              else "no-roofline-section")
+    if failed:
+        print(f"phases,FAIL,{' '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

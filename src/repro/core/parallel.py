@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core.combine import combine, reduce_summaries
 from repro.core.spacesaving import (Summary, init_summary, pad_stream, prune,
                                     spacesaving_chunked)
@@ -79,7 +78,7 @@ def butterfly_combine(s: Summary, axis_name: str, *, match_fn=None) -> Summary:
     ``match_fn`` (``kernels.ops.combine_match`` contract) selects the merge
     kernel for every round.
     """
-    p = compat.axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     if p & (p - 1):
         return allgather_combine(s, (axis_name,), match_fn=match_fn)
     for i in range(int(math.log2(p))):
@@ -110,7 +109,7 @@ def _require_bound_axis(axis_name: str, role: str) -> int:
     the misconfiguration named instead.
     """
     try:
-        return compat.axis_size(axis_name)
+        return lax.axis_size(axis_name)
     except (NameError, KeyError):     # the tracers' unbound-axis errors
         raise ValueError(
             f"hierarchical_combine: {role} axis {axis_name!r} is not bound "

@@ -257,13 +257,12 @@ def spacesaving_chunked(s: Summary, stream: jax.Array, *,
 def pvary_summary(s: Summary, axis_names) -> Summary:
     """Mark a (replicated) summary as device-varying inside ``jax.shard_map``.
 
-    JAX ≥0.8 tracks varying-manual-axes: a freshly built init summary is
+    JAX tracks varying-manual-axes: a freshly built init summary is
     unvarying, but a scan carry that went through per-shard updates is
-    varying, so the init must be promoted with ``lax.pvary`` first.
-    On pre-varying-axes jax the promotion is a no-op (repro.compat).
+    varying, so the init must be promoted with ``lax.pcast`` first.
     """
-    from repro.compat import pvary
-    return jax.tree.map(lambda a: pvary(a, axis_names), s)
+    return jax.tree.map(
+        lambda a: lax.pcast(a, tuple(axis_names), to="varying"), s)
 
 
 def pad_stream(stream: jax.Array, multiple: int) -> jax.Array:

@@ -8,7 +8,9 @@ Dispatch policy (``impl``):
                    on TPU, and off-TPU the pure-jnp reference below
                    ``plan.SORTED_MIN_K`` counters with the sorted
                    merge-join above it (``match_weights`` stays jnp).
-  * ``'pallas'`` — force the kernel (interpret=True off-TPU): used by tests.
+  * ``'pallas'`` — force the kernel: compiled by Mosaic on TPU, evaluated
+                   in interpret mode on the CPU backend (tests); any other
+                   backend raises instead of silently interpreting.
   * ``'jnp'``    — force the reference.
   * ``'sorted'`` — sort + searchsorted merge-join (kernels/ref.py): O((k+c)·
                    log k) instead of the dense k×c matrix; the fast path for
@@ -45,6 +47,23 @@ EMPTY = -1
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _interpret() -> bool:
+    """Whether Pallas kernels run in interpret mode on this backend.
+
+    Compiled on TPU; interpreted on the CPU backend only (the test and
+    rehearsal path). Any other backend is refused: interpreting there
+    would hide the device behind a slow emulation instead of failing.
+    """
+    if _on_tpu():
+        return False
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for TPU or interpret on CPU; backend "
+        f"{backend!r} is neither — pick impl='jnp' or 'sorted' there")
 
 
 # -- memoized plan resolution -------------------------------------------------
@@ -108,7 +127,7 @@ def match_weights(s_items: jax.Array, h_items: jax.Array, h_weights: jax.Array,
     hp = _pad1(h_items, bc, EMPTY)
     wp = _pad1(h_weights.astype(jnp.int32), bc, 0)
     add_w, matched = match_weights_pallas(
-        sp, hp, wp, block_k=bk, block_c=bc, interpret=not _on_tpu())
+        sp, hp, wp, block_k=bk, block_c=bc, interpret=_interpret())
     return add_w[:k].astype(h_weights.dtype), matched[:c]
 
 
@@ -150,7 +169,7 @@ def combine_match(s_items: jax.Array, c_items: jax.Array,
     cep = _pad1((jnp.zeros_like(c_counts) if c_errors is None
                  else c_errors).astype(jnp.int32), bc, 0)
     add_c, add_e, ms, mc = combine_match_pallas(
-        sp, cip, ccp, cep, block_k=bk, block_c=bc, interpret=not _on_tpu())
+        sp, cip, ccp, cep, block_k=bk, block_c=bc, interpret=_interpret())
     return (add_c[:k].astype(c_counts.dtype),
             None if c_errors is None else add_e[:k].astype(c_errors.dtype),
             ms[:k], mc[:c])
@@ -187,7 +206,7 @@ def query(s_items, s_counts, s_errors, queries, *, impl: str = "auto",
     ep = _pad1(s_errors.astype(jnp.int32), bk, 0)
     qp = _pad1(queries, bq, EMPTY)
     f_hat, eps, mon = query_pallas(
-        sp, cp, ep, qp, block_k=bk, block_q=bq, interpret=not _on_tpu())
+        sp, cp, ep, qp, block_k=bk, block_q=bq, interpret=_interpret())
     return (f_hat[:q].astype(s_counts.dtype), eps[:q].astype(s_errors.dtype),
             mon[:q])
 
@@ -232,7 +251,7 @@ def ingest_window(s_items: jax.Array, s_counts: jax.Array,
                                           window)
     if impl == "fused":
         from repro.kernels.ss_ingest import fused_ingest_pallas
-        out = fused_ingest_pallas(si, sc, se, w, interpret=not _on_tpu())
+        out = fused_ingest_pallas(si, sc, se, w, interpret=_interpret())
     else:
         from repro.core.spacesaving import Summary, update_chunk
         match = functools.partial(combine_match, impl=impl)
@@ -262,7 +281,7 @@ def combine_summaries(s1_items: jax.Array, s1_counts: jax.Array,
     if impl == "fused":
         from repro.kernels.ss_ingest import fused_combine_pallas
         out = fused_combine_pallas(a_i, a_c, a_e, b_i, b_c, b_e,
-                                   interpret=not _on_tpu())
+                                   interpret=_interpret())
     else:
         from repro.core.combine import combine
         from repro.core.spacesaving import Summary

@@ -18,8 +18,8 @@ in int32 (exact at any count).
 Grid: (k/BK, c/BC) with the c-axis minor, so the three summary-side outputs
 (add_c, add_e, matched_s) are revisited on consecutive grid steps and
 accumulate in VMEM (init at j == 0). ``matched_c`` partials are written once
-per tile into a (k/BK, c) scratch-out and OR-reduced by the caller — exactly
-the ss_match convention.
+per tile into a (SUBLANES·k/BK, c) scratch-out and OR-reduced by the caller —
+exactly the ss_match convention.
 """
 from __future__ import annotations
 
@@ -28,6 +28,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.ss_match import SUBLANES
 
 EMPTY = -1
 
@@ -56,8 +58,9 @@ def _combine_kernel(s_ref, ci_ref, cc_ref, ce_ref,
     addc_ref[...] += part_c
     adde_ref[...] += part_e
     ms_ref[...] = jnp.maximum(ms_ref[...], part_m)
-    # one write per (i, j) tile; caller ORs over the i axis.
-    mc_ref[...] = eq.any(axis=0, keepdims=True).astype(jnp.int32)
+    # one (SUBLANES, BC) write per (i, j) tile; caller ORs over the rows.
+    mc_ref[...] = jnp.broadcast_to(
+        eq.any(axis=0, keepdims=True).astype(jnp.int32), mc_ref.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "block_c", "interpret"))
@@ -92,13 +95,13 @@ def combine_match_pallas(s_items: jax.Array, c_items: jax.Array,
             pl.BlockSpec((block_k, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((block_k, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((block_k, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, block_c), lambda i, j: (i, j)),
+            pl.BlockSpec((SUBLANES, block_c), lambda i, j: (i, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, 1), jnp.int32),
             jax.ShapeDtypeStruct((k, 1), jnp.int32),
             jax.ShapeDtypeStruct((k, 1), jnp.int32),
-            jax.ShapeDtypeStruct((nk, c), jnp.int32),
+            jax.ShapeDtypeStruct((SUBLANES * nk, c), jnp.int32),
         ],
         interpret=interpret,
     )(s2, ci2, cc2, ce2)

@@ -13,11 +13,15 @@ does the contraction (weights are chunk counts ≤ 2^24, exact in f32).
 Grid: (k/BK, c/BC) with the c-axis minor, so the ``add_w`` output block for
 row-tile i is revisited on *consecutive* grid steps (required on TPU for
 accumulating outputs). ``matched`` partials are written once per tile into a
-(k/BK, c) scratch-out and OR-reduced by the caller — this avoids a second,
-conflicting revisit order in the same kernel.
+(SUBLANES·k/BK, c) scratch-out and OR-reduced by the caller — this avoids a
+second, conflicting revisit order in the same kernel.
 
 Layout: all operands are kept 2-D ((k,1) and (1,c)) — Mosaic wants ≥2-D
-tiles, and the (8,128)-lane VREG layout then maps naturally.
+tiles, and the (8,128)-lane VREG layout then maps naturally. Mosaic also
+wants the last two dims of every block divisible by (8, 128) or equal to the
+array's, so each tile's matched partial is written as a full (SUBLANES, BC)
+sublane group (the row broadcast) rather than one (1, BC) row of an
+(k/BK, c) array — the latter only lowers when k/BK == 1.
 """
 from __future__ import annotations
 
@@ -28,6 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 EMPTY = -1
+SUBLANES = 8      # rows of one int32 vreg tile: the matched-partials block
 
 
 def _match_kernel(s_ref, h_ref, w_ref, add_ref, matched_ref):
@@ -49,8 +54,9 @@ def _match_kernel(s_ref, h_ref, w_ref, add_ref, matched_ref):
         add_ref[...] = jnp.zeros_like(add_ref)
 
     add_ref[...] += partial.astype(add_ref.dtype)
-    # one write per (i, j) tile; caller ORs over the i axis.
-    matched_ref[...] = eq.any(axis=0, keepdims=True).astype(jnp.int32)
+    # one (SUBLANES, BC) write per (i, j) tile; caller ORs over the rows.
+    matched_ref[...] = jnp.broadcast_to(
+        eq.any(axis=0, keepdims=True).astype(jnp.int32), matched_ref.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "block_c", "interpret"))
@@ -81,11 +87,11 @@ def match_weights_pallas(s_items: jax.Array, h_items: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((block_k, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, block_c), lambda i, j: (i, j)),
+            pl.BlockSpec((SUBLANES, block_c), lambda i, j: (i, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, 1), jnp.int32),
-            jax.ShapeDtypeStruct((nk, c), jnp.int32),
+            jax.ShapeDtypeStruct((SUBLANES * nk, c), jnp.int32),
         ],
         interpret=interpret,
     )(s2, h2, w2)

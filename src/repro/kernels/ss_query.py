@@ -9,6 +9,12 @@ accumulate across consecutive steps.
     f̂[q]  = Σ_i [s_items[i] == queries[q]] · s_counts[i]
     ε[q]  = Σ_i [s_items[i] == queries[q]] · s_errors[i]
     mon[q] = ∃i [s_items[i] == queries[q]]
+
+The two weighted reductions are int32 select+sum on the VPU, as in
+ss_combine.py, exact at any count. An f32 MXU contraction here was not:
+on a TPU v5e it returned counts off by up to 2.8e4 against the jnp
+reference for counts below 2^24 (the f32 dot runs in reduced-precision
+passes), so served point reads disagreed with the summary.
 """
 from __future__ import annotations
 
@@ -30,15 +36,9 @@ def _query_kernel(q_ref, s_ref, c_ref, e_ref, f_ref, eps_ref, mon_ref):
     err = e_ref[...]      # (BK, 1) int32
 
     eq = (s == q) & (s != EMPTY)                       # (BK, BQ)
-    eqf = eq.astype(jnp.float32)
-    f_part = jax.lax.dot_general(                       # (1, BQ) = cntᵀ @ eq
-        cnt.astype(jnp.float32), eqf,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    e_part = jax.lax.dot_general(
-        err.astype(jnp.float32), eqf,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    zero = jnp.zeros((), jnp.int32)
+    f_part = jnp.where(eq, cnt, zero).sum(axis=0, keepdims=True)  # (1, BQ)
+    e_part = jnp.where(eq, err, zero).sum(axis=0, keepdims=True)
     m_part = eq.any(axis=0, keepdims=True).astype(jnp.int32)
 
     @pl.when(i == 0)
@@ -47,8 +47,8 @@ def _query_kernel(q_ref, s_ref, c_ref, e_ref, f_ref, eps_ref, mon_ref):
         eps_ref[...] = jnp.zeros_like(eps_ref)
         mon_ref[...] = jnp.zeros_like(mon_ref)
 
-    f_ref[...] += f_part.astype(f_ref.dtype)
-    eps_ref[...] += e_part.astype(eps_ref.dtype)
+    f_ref[...] += f_part
+    eps_ref[...] += e_part
     mon_ref[...] = jnp.maximum(mon_ref[...], m_part)
 
 

@@ -4,20 +4,20 @@ never touches jax device state (required for the dry-run's XLA_FLAGS dance).
 from __future__ import annotations
 
 import jax
-
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return make_mesh_shape(shape, axes)
 
 
 def make_mesh_shape(shape, axes):
-    """Arbitrary mesh (tests, PP experiments)."""
-    return make_mesh(shape, axes)
+    """Arbitrary mesh (tests, PP experiments), every axis explicit-Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n_data: int | None = 1, n_model: int = 1):
@@ -36,4 +36,4 @@ def make_host_mesh(n_data: int | None = 1, n_model: int = 1):
             f"{n_data * n_model} devices but only {n} host device(s) are "
             f"available; lower n_data/n_model or force more via "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N")
-    return make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh_shape((n_data, n_model), ("data", "model"))

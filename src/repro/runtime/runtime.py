@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map_unchecked
 from repro.core.parallel import block_decompose
 from repro.core.spacesaving import Summary
 from repro.engine import SketchEngine
@@ -149,10 +148,10 @@ class StreamRuntime:
         # the replication check rejects the engine's auto-flush cond
         # (replicated-vs-varying branch mismatch); bitwise-equivalence
         # tests against the single-host engine guard correctness instead
-        smap_ingest = shard_map_unchecked(
+        smap_ingest = jax.shard_map(
             shard_ingest, mesh=self.mesh,
             in_specs=state_specs + (P(), spec1),
-            out_specs=state_specs)
+            out_specs=state_specs, check_vma=False)
 
         depth = self.config.engine.buffer_depth
         chunk = self.config.engine.chunk
@@ -181,10 +180,10 @@ class StreamRuntime:
             merged = eng._merged(st)
             return jax.tree.map(lambda a: a[None], merged)
 
-        smap_merged = shard_map_unchecked(
+        smap_merged = jax.shard_map(
             shard_merged, mesh=self.mesh,
             in_specs=state_specs + (P(),),
-            out_specs=Summary(spec1, spec1, spec1))
+            out_specs=Summary(spec1, spec1, spec1), check_vma=False)
 
         def merged(state: SketchState) -> Summary:
             stacked = smap_merged(state.summary, state.buffer, state.n,

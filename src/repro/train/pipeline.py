@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import pvary, shard_map
-
 
 def pipeline_apply(stage_fn, stage_params, x, *, mesh, n_micro: int,
                    axis: str = "pipe"):
@@ -44,8 +42,8 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh, n_micro: int,
         m = xs.shape[0]
         ticks = m + n_stages - 1
 
-        buf0 = pvary(jnp.zeros_like(xs[0]), (axis,))
-        out0 = pvary(jnp.zeros_like(xs), (axis,))
+        buf0 = lax.pcast(jnp.zeros_like(xs[0]), (axis,), to="varying")
+        out0 = lax.pcast(jnp.zeros_like(xs), (axis,), to="varying")
         fwd_perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
 
         def tick(carry, t):
@@ -71,7 +69,7 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh, n_micro: int,
         return outs[None]
 
     specs_p = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(specs_p, P()), out_specs=P(axis),
     )(stage_params, x)[0]

@@ -49,6 +49,17 @@ def test_match_weights_sorted_empty_slots(rng):
                                   [False, True, False, True, False])
 
 
+def test_pallas_interprets_on_cpu_only(monkeypatch):
+    """Compiled on TPU, interpreted on CPU, refused on any other backend."""
+    assert ops._interpret() is True          # the tests' CPU backend
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    assert ops._interpret() is False
+    monkeypatch.setattr(ops, "_on_tpu", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu' is neither"):
+        ops._interpret()
+
+
 def test_match_empty_never_matches(rng):
     si = jnp.asarray([-1, -1, 3], jnp.int32)
     hi = jnp.asarray([-1, 3, 7], jnp.int32)

@@ -280,7 +280,7 @@ def test_mesh_reductions_route_kernel_and_agree():
     out = _run("""
 import functools, jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import init_summary, spacesaving_chunked
 from repro.core.parallel import (allgather_combine, butterfly_combine,
                                  hierarchical_combine)
@@ -324,7 +324,7 @@ def test_butterfly_non_power_of_two_axis_falls_back():
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import init_summary, spacesaving_chunked
 from repro.core.parallel import allgather_combine, butterfly_combine
 from repro.core.spacesaving import pvary_summary

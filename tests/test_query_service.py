@@ -102,10 +102,9 @@ def test_query_auto_matches_explicit_ref(k):
 
 def test_query_wide_dtype_never_hits_pallas():
     """int64 counts route to the exact sorted path instead of truncating."""
-    import jax.experimental
     s = _summary_at_fill(64, 1.0, seed=3)
     q = _query_mix(s, seed=3)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         big = s.counts.astype(jnp.int64) + jnp.asarray(2**33, jnp.int64)
         f, eps, mon = ops.query(s.items, big, s.errors.astype(jnp.int64),
                                 q, impl="pallas")

@@ -93,12 +93,14 @@ def test_runtime_shards_exceed_devices():
 
 
 def test_hierarchical_missing_cross_pod_axis_is_clear():
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+
     from repro.core import hierarchical_combine, init_summary
     from repro.core.spacesaving import pvary_summary
+    from repro.launch.mesh import make_mesh_shape
 
-    mesh = make_mesh((1,), ("data",))
+    mesh = make_mesh_shape((1,), ("data",))
 
     def run():
         def inner(_):

@@ -54,7 +54,7 @@ def test_butterfly_and_hierarchical_reductions_agree():
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import *
 from repro.core.spacesaving import pvary_summary
 from repro.core.exact import evaluate, overestimation_violations

@@ -1,0 +1,92 @@
+"""Compile rehearsal for the chip: the main path's Pallas kernels and one
+engine ingest step, compiled for a described TPU v5e with no chip attached.
+
+Interpret mode cannot show what Mosaic refuses (block tiling, VMEM); these
+compiles can. The topology is described inside a fixture, never at import:
+only one process may hold the TPU compiler library, and every test worker
+imports this file. The persistent compilation cache is off around these
+compiles — what they write could not be read back without a chip.
+"""
+import functools
+
+import pytest
+
+PAPER_K, WINDOW, QUERIES = 2000, 16384, 512   # Table I k; T·C; read batch
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Steer the kernel wrappers to lower for Mosaic, not to interpret."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+
+
+def _shapes(sharding, *sizes):
+    import jax
+    import jax.numpy as jnp
+    return [jax.ShapeDtypeStruct((n,), jnp.int32, sharding=sharding)
+            for n in sizes]
+
+
+def _kernel_cases():
+    from repro.kernels import ops
+    return {
+        "combine_match": (ops.combine_match,
+                          (PAPER_K, WINDOW, WINDOW, WINDOW)),
+        "match_weights": (ops.match_weights, (PAPER_K, WINDOW, WINDOW)),
+        "query": (ops.query, (PAPER_K, PAPER_K, PAPER_K, QUERIES)),
+    }
+
+
+@pytest.mark.parametrize("op", ["combine_match", "match_weights", "query"])
+def test_kernel_compiles_at_paper_k(op, one_chip, on_tpu):
+    import jax
+    fn, sizes = _kernel_cases()[op]
+    compiled = jax.jit(functools.partial(fn, impl="pallas")).lower(
+        *_shapes(one_chip, *sizes)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_engine_ingest_step_compiles_at_paper_default(one_chip, on_tpu):
+    """One SketchEngine._ingest step at PAPER_STREAM_CONFIGS
+    ["paper-default"] with the serving geometry (8 lanes, chunk 2048,
+    depth 8) and the Pallas matcher the static plan picks on a TPU."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs.registry import PAPER_STREAM_CONFIGS
+    from repro.engine import EngineConfig, SketchEngine
+
+    k = PAPER_STREAM_CONFIGS["paper-default"]["k_counters"]
+    eng = SketchEngine(EngineConfig(k=k, tenants=8, chunk=2048,
+                                    buffer_depth=8, kernel="pallas"))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng.state_shapes())
+    block = jax.ShapeDtypeStruct((8, 2048), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(eng._ingest).lower(state, block).compile()
+    assert "tpu_custom_call" in compiled.as_text()
