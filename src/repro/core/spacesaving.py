@@ -113,6 +113,7 @@ def spacesaving_scan(s: Summary, stream: jax.Array) -> Summary:
 # Chunked TPU-native update
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("sketch.histogram")
 def chunk_histogram(chunk: jax.Array, count_dtype=jnp.int32):
     """Exact histogram of one chunk via sort + segment reduction.
 
@@ -133,6 +134,7 @@ def chunk_histogram(chunk: jax.Array, count_dtype=jnp.int32):
     return items, weights
 
 
+@jax.named_scope("sketch.topk")
 def merge_pool(s: Summary, cand_items, cand_counts, cand_errors) -> Summary:
     """top-k prune of (summary ∪ candidates) — the eviction step, vectorized.
 
@@ -182,33 +184,35 @@ def absorb_pool(s: Summary, cand_items: jax.Array, cand_counts: jax.Array,
         from repro.kernels import ops as _kops
         match_fn = _kops.combine_match
     dtype = s.counts.dtype
-    m1 = min_frequency(s)
-    add_c, add_e, matched_s, matched_c = match_fn(
-        s.items, cand_items, cand_counts, cand_errors)
+    with jax.named_scope("sketch.match"):
+        add_c, add_e, matched_s, matched_c = match_fn(
+            s.items, cand_items, cand_counts, cand_errors)
 
-    valid1 = s.items != EMPTY
-    m2 = jnp.asarray(m2, dtype)
-    zero = jnp.zeros((), dtype)
-    inc_c = jnp.where(matched_s, add_c.astype(dtype), m2)
-    inc_e = jnp.where(matched_s, zero if add_e is None else add_e.astype(dtype),
-                      m2)
-    upd = Summary(
-        items=s.items,
-        counts=jnp.where(valid1, s.counts + inc_c, 0),
-        errors=jnp.where(valid1, s.errors + inc_e, 0),
-    )
+    with jax.named_scope("sketch.absorb"):
+        m1 = min_frequency(s)
+        valid1 = s.items != EMPTY
+        m2 = jnp.asarray(m2, dtype)
+        zero = jnp.zeros((), dtype)
+        inc_c = jnp.where(matched_s, add_c.astype(dtype), m2)
+        inc_e = jnp.where(matched_s,
+                          zero if add_e is None else add_e.astype(dtype), m2)
+        upd = Summary(
+            items=s.items,
+            counts=jnp.where(valid1, s.counts + inc_c, 0),
+            errors=jnp.where(valid1, s.errors + inc_e, 0),
+        )
 
-    # only unmatched valid candidates survive into the pool (+m₁ offsets);
-    # invalid ones carry count -1 so top_k can never pick them over a real
-    # (or even an empty, count-0) counter.
-    cand_valid = (cand_items != EMPTY) & ~matched_c
-    ce = zero if cand_errors is None else cand_errors.astype(dtype)
-    cand = (
-        jnp.where(cand_valid, cand_items, EMPTY),
-        jnp.where(cand_valid, cand_counts.astype(dtype) + m1,
-                  jnp.asarray(-1, dtype)),
-        jnp.where(cand_valid, ce + m1, 0),
-    )
+        # only unmatched valid candidates survive into the pool (+m₁
+        # offsets); invalid ones carry count -1 so top_k can never pick
+        # them over a real (or even an empty, count-0) counter.
+        cand_valid = (cand_items != EMPTY) & ~matched_c
+        ce = zero if cand_errors is None else cand_errors.astype(dtype)
+        cand = (
+            jnp.where(cand_valid, cand_items, EMPTY),
+            jnp.where(cand_valid, cand_counts.astype(dtype) + m1,
+                      jnp.asarray(-1, dtype)),
+            jnp.where(cand_valid, ce + m1, 0),
+        )
     return merge_pool(upd, *cand)
 
 

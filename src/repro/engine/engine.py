@@ -120,11 +120,18 @@ class SketchEngine:
     # -- updates ------------------------------------------------------------
 
     def _flush_view(self, state: SketchState) -> Summary:
-        """The summaries as if the pending buffer were merged now (pure)."""
-        if self.config.flush_mode == "deferred":
-            return flushed_summary(state, match_fn=self._match_fn,
-                                   window_fn=self._window_fn)
-        return replayed_summary(state, match_fn=self._match_fn)
+        """The summaries as if the pending buffer were merged now (pure).
+
+        Traced under the ``sketch.flush`` name scope; its stages carry
+        ``sketch.histogram``, ``sketch.match``, ``sketch.absorb`` and
+        ``sketch.topk`` (``core/spacesaving.py``), so a profile's per-op
+        events name the flush stage they belong to.
+        """
+        with jax.named_scope("sketch.flush"):
+            if self.config.flush_mode == "deferred":
+                return flushed_summary(state, match_fn=self._match_fn,
+                                       window_fn=self._window_fn)
+            return replayed_summary(state, match_fn=self._match_fn)
 
     def _flush(self, state: SketchState) -> SketchState:
         return SketchState(summary=self._flush_view(state),
@@ -206,14 +213,16 @@ class SketchEngine:
         block-boundary snapshot. The cond skips it and pays only the
         reduction, bitwise-identically (merging an EMPTY window never
         changes a summary; asserted per kernel × flush mode in
-        tests/test_serve.py).
+        tests/test_serve.py). Traced under the ``sketch.merge`` name
+        scope (the publish merge of ``StreamRuntime.merged``).
         """
         axes = tuple(self.config.axis_names)
-        return lax.cond(
-            state.fill == 0,
-            lambda st: self._reduce(st.summary, axes),
-            lambda st: self._reduce(self._flush_view(st), axes),
-            state)
+        with jax.named_scope("sketch.merge"):
+            return lax.cond(
+                state.fill == 0,
+                lambda st: self._reduce(st.summary, axes),
+                lambda st: self._reduce(self._flush_view(st), axes),
+                state)
 
     def _top(self, state: SketchState, n: int = 10):
         # n is clamped to [0, k]: slicing past k would silently return k

@@ -38,6 +38,8 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Tuple
 
+import jax
+
 from repro.core.combine import reduce_summaries
 from repro.core.parallel import (allgather_combine, butterfly_combine,
                                  hierarchical_combine)
@@ -71,38 +73,48 @@ def reduction_names():
 # Built-ins
 # ---------------------------------------------------------------------------
 
+def _lanes(stacked: Summary, match_fn, pair_fn) -> Summary:
+    """The on-device tree over the local tenant dim (``sketch.lane_reduce``
+    in a profile's per-op names)."""
+    with jax.named_scope("sketch.lane_reduce"):
+        return reduce_summaries(stacked, match_fn=match_fn, pair_fn=pair_fn)
+
+
 def _local(stacked: Summary, axis_names, *, match_fn=None,
            pair_fn=None) -> Summary:
-    return reduce_summaries(stacked, match_fn=match_fn, pair_fn=pair_fn)
+    return _lanes(stacked, match_fn, pair_fn)
 
 
 def _butterfly(stacked: Summary, axis_names, *, match_fn=None,
                pair_fn=None) -> Summary:
-    s = reduce_summaries(stacked, match_fn=match_fn, pair_fn=pair_fn)
-    for ax in axis_names:
-        s = butterfly_combine(s, ax, match_fn=match_fn)
+    s = _lanes(stacked, match_fn, pair_fn)
+    with jax.named_scope("sketch.mesh_reduce"):
+        for ax in axis_names:
+            s = butterfly_combine(s, ax, match_fn=match_fn)
     return s
 
 
 def _allgather(stacked: Summary, axis_names, *, match_fn=None,
                pair_fn=None) -> Summary:
-    s = reduce_summaries(stacked, match_fn=match_fn, pair_fn=pair_fn)
+    s = _lanes(stacked, match_fn, pair_fn)
     if axis_names:
         # all_gather stacks one dim per axis in the order given; reversing
         # the innermost-first convention gathers outermost-first, i.e. the
         # mesh-major global rank order the canonical COMBINE tree expects
-        s = allgather_combine(s, tuple(reversed(axis_names)),
-                              match_fn=match_fn)
+        with jax.named_scope("sketch.mesh_reduce"):
+            s = allgather_combine(s, tuple(reversed(axis_names)),
+                                  match_fn=match_fn)
     return s
 
 
 def _hierarchical(stacked: Summary, axis_names, *, match_fn=None,
                   pair_fn=None) -> Summary:
-    s = reduce_summaries(stacked, match_fn=match_fn, pair_fn=pair_fn)
+    s = _lanes(stacked, match_fn, pair_fn)
     if axis_names:
         inner = axis_names[0]
         outer = axis_names[1] if len(axis_names) > 1 else None
-        s = hierarchical_combine(s, inner, outer, match_fn=match_fn)
+        with jax.named_scope("sketch.mesh_reduce"):
+            s = hierarchical_combine(s, inner, outer, match_fn=match_fn)
     return s
 
 

@@ -39,6 +39,7 @@ import time
 import numpy as np
 
 from repro.core.spacesaving import EMPTY
+from repro.obs import trace as obs_trace
 
 
 def sketch_health(snap, k_majority: int | None = None) -> dict:
@@ -157,14 +158,17 @@ class HealthMonitor:
     reads exactly this gauge). With a :class:`~repro.obs.drift.
     DriftEstimator` attached, every health refresh also refreshes the
     drift frame from the same snapshot — one materialization feeds
-    both.
+    both. Each refresh opens two sibling spans on ``tracer``:
+    ``ingest.observe.health`` around the gauges (the materialization
+    included) and ``ingest.observe.drift`` around the drift frame.
     """
 
     def __init__(self, ring, registry, *, k_majority: int | None = None,
-                 poll_s: float = 0.1, drift=None):
+                 poll_s: float = 0.1, drift=None, tracer=None):
         self.ring = ring
         self.gauges = HealthGauges(registry, k_majority=k_majority)
         self.drift = drift
+        self.tracer = obs_trace.NULL if tracer is None else tracer
         self._poll_s = poll_s
         self._age_gauge = registry.gauge("health.last_refresh_age_s")
         self._last_refresh_t: float | None = None
@@ -205,11 +209,13 @@ class HealthMonitor:
 
     def _apply(self, snap) -> dict:
         """Gauges + drift frame from one snapshot; stamps the age clock."""
-        h = self.gauges.update(snap)
+        with self.tracer.span("ingest.observe.health"):
+            h = self.gauges.update(snap)
         self._last_refresh_t = time.perf_counter()
         self._age_gauge.set(0.0)
         if self.drift is not None:
-            self.drift.update(snap, h, self._last_refresh_t)
+            with self.tracer.span("ingest.observe.drift"):
+                self.drift.update(snap, h, self._last_refresh_t)
         return h
 
     def _tick_age(self) -> None:

@@ -188,7 +188,7 @@ class FlightRecorder:
             err = {"type": type(error).__name__, "message": str(error),
                    "traceback": "".join(traceback.format_exception(
                        type(error), error, error.__traceback__))}
-        spans = self.tracer.events()[-self.span_tail:]
+        spans = self.tracer.events(last=self.span_tail)
         record = {
             "schema": SCHEMA,
             "reason": reason,

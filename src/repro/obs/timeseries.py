@@ -45,6 +45,8 @@ import time
 
 import numpy as np
 
+from repro.obs import trace as obs_trace
+
 DEFAULT_CAPACITY = 512          # samples per series (~2 min at 4 Hz)
 
 
@@ -367,16 +369,18 @@ class MetricsSampler:
     capture there, so the whole sentinel costs the serving hot path
     nothing (DESIGN.md §14). ``tick()`` pumps once synchronously for
     callers that own their own cadence (tests, the --watch CLI's final
-    frame)."""
+    frame). Each pump, hook included, is one ``ingest.observe.tick`` span
+    on ``tracer``."""
 
     def __init__(self, registry, *, interval_s: float = 0.25,
-                 on_sample=None):
+                 on_sample=None, tracer=None):
         if interval_s <= 0:
             raise ValueError(
                 f"interval_s must be > 0, got {interval_s}")
         self.registry = registry
         self.interval_s = interval_s
         self.on_sample = on_sample
+        self.tracer = obs_trace.NULL if tracer is None else tracer
         self._stop = threading.Event()
         self._thread = threading.Thread(
             target=self._run, name="repro-obs-sampler", daemon=True)
@@ -391,9 +395,10 @@ class MetricsSampler:
 
     def tick(self, t: float | None = None) -> float:
         """One synchronous pump (sample + on_sample hook)."""
-        t = self.registry.sample(t)
-        if t is not None and self.on_sample is not None:
-            self.on_sample(t)
+        with self.tracer.span("ingest.observe.tick"):
+            t = self.registry.sample(t)
+            if t is not None and self.on_sample is not None:
+                self.on_sample(t)
         return t
 
     def stop(self, timeout: float | None = 5.0) -> None:

@@ -1,8 +1,8 @@
 """Trace — nested span context managers over a bounded JSON-lines ring.
 
 The qualitative half of the obs layer (DESIGN.md §12): ``span("name")``
-times a region, tracks nesting per thread, and appends one event dict to
-a fixed-capacity ring when the region closes — O(1) memory no matter how
+times a region, tracks nesting per thread, and appends one event to a
+fixed-capacity ring when the region closes — O(1) memory no matter how
 long the tier runs, oldest events evicted first. ``event()`` records
 instant (zero-duration) marks; ``log()`` additionally renders the mark as
 one structured ``[name] key=value`` line, which is how the serving CLIs
@@ -12,11 +12,18 @@ Span events record completion order (a child closes before its parent),
 with ``id``/``parent``/``depth`` carrying the nesting so consumers can
 rebuild the tree; ``t`` is a ``time.perf_counter()`` timestamp, so
 deltas — not absolute times — are meaningful within one process. Each
-event additionally carries ``epoch`` (wall-clock ``time.time()``),
-``pid`` and ``tid``, so exports from multiple tiers/processes can be
-merged on a shared clock and correlated with flight-recorder dumps;
-``export(since_event_id=...)`` tails the ring incrementally by the
-monotone event id.
+event additionally carries ``start_ns``, its start on the host clock the
+JAX profiler stamps its own host events with (``time.time_ns()``: a
+``.xplane.pb`` holds each host event at ``profile_start_time`` plus its
+offset), so a flight-recorder dump can be laid over a device trace;
+``epoch`` is the same instant in seconds. ``pid``, ``tid`` and
+``thread`` let exports from multiple tiers/processes be merged and
+correlated with flight-recorder dumps; ``export(since_event_id=...)``
+tails the ring incrementally by the monotone event id.
+
+Recording is kept cheap for the hot loops that open spans: a closed span
+rings one tuple, the process id is taken once per tracer and a thread's
+name once per thread; the event dicts are built when the ring is read.
 
 With ``annotate=True`` every span also enters a
 ``jax.profiler.TraceAnnotation`` of the same name, so device timelines
@@ -26,7 +33,6 @@ pass-through degrades to a no-op when the profiler is unavailable.
 from __future__ import annotations
 
 import collections
-import contextlib
 import itertools
 import json
 import os
@@ -44,6 +50,55 @@ def fmt_event(name: str, fields: dict) -> str:
     return " ".join(parts)
 
 
+class _Span:
+    """One open span of a :class:`Tracer`; rings its record on close."""
+
+    __slots__ = ("_tracer", "_name", "_attrs", "_ann", "_stack", "_sid",
+                 "_parent", "_depth", "_tid", "_t0", "_ns0")
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+        self._tracer = tracer
+        self._name = name
+        self._attrs = attrs or None
+
+    def __enter__(self) -> int:
+        tr = self._tracer
+        stack = tr._stack()
+        sid = next(tr._ids)
+        self._parent = stack[-1] if stack else 0
+        self._depth = len(stack)
+        self._ann = None
+        if tr._annotation is not None:      # device-timeline pass-through
+            self._ann = tr._annotation(self._name)
+            self._ann.__enter__()
+        stack.append(sid)
+        self._stack = stack
+        self._sid = sid
+        self._tid = threading.get_ident()
+        self._ns0 = time.time_ns()
+        self._t0 = time.perf_counter()
+        return sid
+
+    def __exit__(self, *exc) -> bool:
+        dur = time.perf_counter() - self._t0
+        self._stack.pop()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._tracer._append(("span", self._sid, self._parent, self._depth,
+                              self._name, self._t0, dur, self._ns0,
+                              self._tid, self._attrs))
+        return False
+
+
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation``, or None without the profiler."""
+    try:
+        from jax.profiler import TraceAnnotation
+        return TraceAnnotation
+    except Exception:                       # profiler unavailable
+        return None
+
+
 class Tracer:
     """Per-scope span/event recorder with a bounded event ring."""
 
@@ -54,8 +109,11 @@ class Tracer:
         self._lock = threading.Lock()
         self._tls = threading.local()
         self._ids = itertools.count(1)
+        self._pid = os.getpid()
+        self._threads: dict = {}            # tid -> name, once per thread
         self.capacity = capacity
         self.annotate = annotate
+        self._annotation = _annotation_type() if annotate else None
 
     # -- recording -----------------------------------------------------------
 
@@ -63,58 +121,26 @@ class Tracer:
         st = getattr(self._tls, "stack", None)
         if st is None:
             st = self._tls.stack = []
+            t = threading.current_thread()
+            self._threads[t.ident] = t.name
         return st
 
-    def _append(self, ev: dict) -> None:
+    def _append(self, rec: tuple) -> None:
         with self._lock:
-            self._events.append(ev)
+            self._events.append(rec)
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs):
+    def span(self, name: str, **attrs) -> _Span:
         """Time a nested region; the event is ringed when it closes."""
-        stack = self._stack()
-        sid = next(self._ids)
-        parent = stack[-1] if stack else 0
-        depth = len(stack)
-        ann = None
-        if self.annotate:                   # device-timeline pass-through
-            try:
-                from jax.profiler import TraceAnnotation
-                ann = TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:               # profiler unavailable → host-only
-                ann = None
-        stack.append(sid)
-        t0 = time.perf_counter()
-        try:
-            yield sid
-        finally:
-            dur = time.perf_counter() - t0
-            stack.pop()
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            ev = {"kind": "span", "id": sid, "parent": parent,
-                  "depth": depth, "name": name, "t": t0, "dur_s": dur,
-                  "epoch": time.time() - dur, "pid": os.getpid(),
-                  "tid": threading.get_ident(),
-                  "thread": threading.current_thread().name}
-            if attrs:
-                ev["attrs"] = attrs
-            self._append(ev)
+        return _Span(self, name, attrs)
 
     def event(self, name: str, **fields) -> dict:
         """Record one instant mark (parented to the active span)."""
         stack = self._stack()
-        ev = {"kind": "event", "id": next(self._ids),
-              "parent": stack[-1] if stack else 0, "depth": len(stack),
-              "name": name, "t": time.perf_counter(),
-              "epoch": time.time(), "pid": os.getpid(),
-              "tid": threading.get_ident(),
-              "thread": threading.current_thread().name}
-        if fields:
-            ev["attrs"] = fields
-        self._append(ev)
-        return ev
+        rec = ("event", next(self._ids), stack[-1] if stack else 0,
+               len(stack), name, time.perf_counter(), None, time.time_ns(),
+               threading.get_ident(), fields or None)
+        self._append(rec)
+        return self._as_dict(rec)
 
     def log(self, name: str, _printer=print, **fields) -> None:
         """``event()`` + one structured stdout line — the CLI surface."""
@@ -123,17 +149,31 @@ class Tracer:
 
     # -- reading -------------------------------------------------------------
 
-    def events(self) -> list:
-        """Ring contents, oldest first (a consistent copy)."""
+    def _as_dict(self, rec: tuple) -> dict:
+        kind, sid, parent, depth, name, t, dur, ns, tid, attrs = rec
+        ev = {"kind": kind, "id": sid, "parent": parent, "depth": depth,
+              "name": name, "t": t}
+        if kind == "span":
+            ev["dur_s"] = dur
+        ev.update(start_ns=ns, epoch=ns / 1e9, pid=self._pid, tid=tid,
+                  thread=self._threads.get(tid, str(tid)))
+        if attrs:
+            ev["attrs"] = attrs
+        return ev
+
+    def _records(self, last: int | None = None) -> list:
         with self._lock:
-            return list(self._events)
+            recs = list(self._events)
+        return recs[-last:] if last is not None else recs
+
+    def events(self, last: int | None = None) -> list:
+        """Ring contents (the newest ``last``), oldest first (a
+        consistent copy)."""
+        return [self._as_dict(r) for r in self._records(last)]
 
     def to_jsonl(self, last: int | None = None) -> str:
         """The (optionally tail-truncated) ring as JSON lines."""
-        evs = self.events()
-        if last is not None:
-            evs = evs[-last:]
-        return "\n".join(json.dumps(e) for e in evs)
+        return "\n".join(json.dumps(e) for e in self.events(last))
 
     def export(self, since_event_id: int = 0,
                last: int | None = None) -> str:
@@ -141,10 +181,10 @@ class Tracer:
         incremental tailing: feed back the max id you've seen and only
         newer events come out (ids are monotone, so eviction from the
         ring can only drop events you would have skipped anyway)."""
-        evs = [e for e in self.events() if e["id"] > since_event_id]
+        recs = [r for r in self._records() if r[1] > since_event_id]
         if last is not None:
-            evs = evs[-last:]
-        return "\n".join(json.dumps(e) for e in evs)
+            recs = recs[-last:]
+        return "\n".join(json.dumps(self._as_dict(r)) for r in recs)
 
     def clear(self) -> None:
         with self._lock:
@@ -179,7 +219,7 @@ class _NullTracer:
     def log(self, name: str, _printer=print, **fields) -> None:
         _printer(fmt_event(name, fields))
 
-    def events(self) -> list:
+    def events(self, last: int | None = None) -> list:
         return []
 
     def to_jsonl(self, last: int | None = None) -> str:

@@ -191,7 +191,6 @@ class IngestLoop:
         reg = self.registry
         self._m_queue_depth = reg.gauge("serve.ingest.queue_depth")
         self._m_step = reg.histogram("serve.ingest.step_s")
-        self._m_publish = reg.histogram("serve.ingest.publish_s")
         self._m_blocks = reg.counter("serve.ingest.blocks")
         self._m_items = reg.counter("serve.ingest.items")
         self._m_shed = reg.counter("serve.ingest.shed")
@@ -330,6 +329,11 @@ class IngestLoop:
         ingest_donated = rt._feed_ingest_fn
         stager = DeviceStager(sharding=rt.block_sharding(),
                               depth=self.feed_depth)
+        # spans of the loop thread (DESIGN.md §12): ``ingest.wait`` while
+        # the queue is empty, ``ingest.stage`` per staged group. Each
+        # jitted dispatch stays directly inside ``ingest.step`` or
+        # ``ingest.publish``, so a profile ties its device time there
+        span = self.tracer.span
         # first call must not donate the caller-provided initial state
         donate_ok = False
         since_publish = 0
@@ -338,7 +342,8 @@ class IngestLoop:
             # block always find a complete (possibly empty) snapshot
             self._publish()
             while True:
-                item = self._queue.get()
+                with span("ingest.wait"):
+                    item = self._queue.get()
                 if item[0] != _BLOCK:
                     kind, payload = item
                     if kind == _STOP:
@@ -388,16 +393,17 @@ class IngestLoop:
                     nonlocal gi
                     while gi < len(groups) and stager.room:
                         g = groups[gi]
-                        arrays = [np.asarray(p) for p in g]
-                        block = coalesce_blocks(arrays, workers, chunk)
-                        items = sum(int(a.size) for a in arrays)
-                        stager.stage(block, (len(g), items))
+                        with span("ingest.stage"):
+                            arrays = [np.asarray(p) for p in g]
+                            block = coalesce_blocks(arrays, workers, chunk)
+                            items = sum(int(a.size) for a in arrays)
+                            stager.stage(block, (len(g), items))
                         gi += 1
 
                 top_up()
                 while len(stager):
                     t0 = time.perf_counter()
-                    with self.tracer.span("ingest.step"):
+                    with span("ingest.step"):
                         dev, (nb, items) = stager.take()
                         top_up()
                         if dev.shape[-1]:
@@ -450,13 +456,12 @@ class IngestLoop:
                     pass                    # mask the original error
 
     def _publish(self) -> QuerySnapshot:
-        # timed around the (async or deferred) dispatch + ring swap: this
-        # is the write path's entire snapshot cost (readers pay
+        # the span covers the (async or deferred) dispatch + ring swap:
+        # the write path's entire snapshot cost (readers pay
         # materialization). Lazy publishes capture the state reference +
         # the writer's own item count (the count_floor ε filter) and ring
         # immediately; the materialized counter tells the bench how many
         # versions a reader ever actually forced.
-        t0 = time.perf_counter()
         lazy = self.lazy_publish
         with self.tracer.span("ingest.publish"):
             snap = self._publisher.publish(
@@ -465,6 +470,5 @@ class IngestLoop:
                 on_materialize=self._m_materialized.inc if lazy else None)
         if lazy:
             self._m_deferred.inc()
-        self._m_publish.record(time.perf_counter() - t0)
         self.stats.add(publishes=1)
         return snap

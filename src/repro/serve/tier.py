@@ -49,7 +49,10 @@ gated by a config knob and all off when ``metrics`` is off:
 Nothing in the sentinel runs on the ingest thread: the sampler and the
 health monitor own the only refresh loops, and the ingest loop's sole
 new obligation is invoking the recorder's error trigger *after* it has
-already captured the failure.
+already captured the failure. Both refresh loops record into the tier's
+tracer (``ingest.observe.health``/``.drift`` per refresh,
+``ingest.observe.tick`` per pump), so their host time shows beside the
+loop's own spans.
 """
 from __future__ import annotations
 
@@ -117,7 +120,7 @@ class ServingTier:
             self.alerts.on_fire = self.recorder.on_alert
         self.sampler = (obs_timeseries.MetricsSampler(
             registry, interval_s=config.sample_interval_s,
-            on_sample=self._on_sample)
+            on_sample=self._on_sample, tracer=tracer)
             if sentinel and config.timeseries else None)
 
         self.loop = IngestLoop(
@@ -132,7 +135,7 @@ class ServingTier:
                                       registry=registry)
         self.health = (obs_health.HealthMonitor(
             self.ring, registry, k_majority=config.health_k_majority,
-            drift=self.drift)
+            drift=self.drift, tracer=tracer)
             if config.metrics else None)
         if self.recorder is not None and self.health is not None:
             self.recorder.health_source = self.health.latest

@@ -59,14 +59,17 @@ def _estimate_fn(impl: str):
 
     @jax.jit
     def run(items, counts, errors, queries):
-        s = Summary(items, counts, errors)
-        f, eps, mon = kops.query(items, counts, errors, queries, impl=impl)
-        return bounded_estimates(s, f, eps, mon)
+        with jax.named_scope("sketch.query.estimate"):
+            s = Summary(items, counts, errors)
+            f, eps, mon = kops.query(items, counts, errors, queries,
+                                     impl=impl)
+            return bounded_estimates(s, f, eps, mon)
 
     return run
 
 
-_sorted_desc = jax.jit(functools.partial(sort_summary, ascending=False))
+_sorted_desc = jax.jit(functools.partial(
+    jax.named_scope("sketch.query.top")(sort_summary), ascending=False))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,6 +1,8 @@
 """SketchEngine: buffered-vs-unbuffered equivalence, COMBINE algebra on
 batched states, invariant preservation after deferred merges, kernel
 dispatch and the reduction registry."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -323,3 +325,45 @@ def test_local_reduction_equals_reduce_summaries():
     st = engine.flush(engine.ingest(engine.init(), jnp.asarray(stream)))
     direct = reduce_summaries(st.summary)
     assert _tree_equal(engine.merged(st), direct)
+
+
+# ---------------------------------------------------------------------------
+# Name scopes: the flush stages, the publish merge and the reads carry
+# their names into the programs' metadata (what a profile's per-op events
+# show)
+# ---------------------------------------------------------------------------
+
+FLUSH_SCOPES = ("sketch.flush", "sketch.histogram", "sketch.match",
+                "sketch.absorb", "sketch.topk")
+
+
+def _scoped_program(kernel, program):
+    """(lowered program, the scope names it must carry)."""
+    from repro.service.frontend import _estimate_fn, _sorted_desc
+    eng = SketchEngine(EngineConfig(k=16, tenants=2, chunk=32,
+                                    buffer_depth=2, kernel=kernel))
+    st = eng.init()
+    s = jax.tree.map(lambda a: a[0], st.summary)
+    if program == "ingest":
+        stream = jnp.asarray(zipf(2 * 64).reshape(2, 64))
+        return eng.ingest.lower(st, stream), FLUSH_SCOPES
+    if program == "merged":
+        return eng.merged.lower(st), FLUSH_SCOPES + ("sketch.merge",
+                                                     "sketch.lane_reduce")
+    if program == "estimate":
+        return (_estimate_fn(kernel).lower(s.items, s.counts, s.errors,
+                                           jnp.zeros((8,), jnp.int32)),
+                ("sketch.query.estimate",))
+    return _sorted_desc.lower(s), ("sketch.query.top",)
+
+
+@pytest.mark.parametrize("kernel", ["jnp", "pallas"])
+@pytest.mark.parametrize("program", ["ingest", "merged", "estimate", "top"])
+def test_lowered_programs_carry_the_scope_names(kernel, program):
+    lowered, scopes = _scoped_program(kernel, program)
+    text = lowered.as_text(debug_info=True)
+    for scope in scopes:
+        # a component of an op's name stack, e.g. ".../sketch.flush/..."
+        # or "vmap(sketch.histogram)/..."
+        assert re.search(rf'[/("]{re.escape(scope)}[/)]', text), (
+            program, scope)

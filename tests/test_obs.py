@@ -277,6 +277,50 @@ def test_span_nesting_is_per_thread():
     assert {e["depth"] for e in tr.events()} == {0}
 
 
+def test_span_start_on_the_profilers_host_clock(tmp_path):
+    """A ring span's ``start_ns`` is where the JAX profiler puts the
+    span's own annotation: ``profile_start_time`` plus the host event's
+    offset, within 1 ms."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer(annotate=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("ingest.clock"):
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    pd = ProfileData.from_file(path)
+    start0 = next(v for p in pd.planes for name, v in p.stats
+                  if name == "profile_start_time")
+    (ann,) = [ev for p in pd.planes for line in p.lines
+              for ev in line.events if ev.name == "ingest.clock"]
+    (ring,) = tr.events()
+    assert abs(start0 + ann.start_ns - ring["start_ns"]) < 1e6
+    assert ring["epoch"] == ring["start_ns"] / 1e9
+    assert ann.duration_ns / 1e9 == pytest.approx(ring["dur_s"], abs=1e-3)
+
+
+def test_thread_names_resolved_when_read():
+    tr = Tracer()
+
+    def work():
+        with tr.span("s"):
+            pass
+
+    t = threading.Thread(target=work, name="repro-test-worker")
+    t.start()
+    t.join()
+    tr.event("main")
+    # the worker has exited; its name was kept once, not per span
+    assert [e["thread"] for e in tr.events()] == [
+        "repro-test-worker", threading.current_thread().name]
+
+
 # ---------------------------------------------------------------------------
 # IngestStats: consistent snapshots under concurrency
 # ---------------------------------------------------------------------------

@@ -579,3 +579,114 @@ def test_reads_interleave_with_ingestion():
         block_n = rt.workers * CHUNK
         for i, n in enumerate(ns):
             assert n % block_n == 0 and n <= (i + 1) * block_n
+
+
+# ---------------------------------------------------------------------------
+# Spans of the loop and the observer threads (what a profile ties device
+# time and host time to)
+# ---------------------------------------------------------------------------
+
+def _spans(tracer):
+    return [e for e in tracer.events() if e["kind"] == "span"]
+
+
+def test_loop_spans_keep_each_launch_in_its_step_or_publish():
+    """``ingest.stage`` is the only ``ingest.*`` child of ``ingest.step``;
+    the jitted ingest runs with ``ingest.step`` innermost and the publish
+    merge with ``ingest.publish`` innermost; ``ingest.wait`` never overlaps
+    a step."""
+    from repro.obs.trace import Tracer
+
+    rt = _runtime()
+    tr = Tracer(capacity=100_000)
+    launched = []               # (program, innermost open span id)
+
+    def spy(kind, fn):
+        def call(*args, **kw):
+            stack = tr._stack()
+            launched.append((kind, stack[-1] if stack else 0))
+            return fn(*args, **kw)
+        return call
+
+    rt._ingest_blocks_fn = spy("ingest", rt._ingest_blocks_fn)
+    rt._feed_ingest_fn = spy("ingest", rt._feed_ingest_fn)
+    rt._merged_fn = spy("merged", rt._merged_fn)
+    blocks = _blocks(rt, 12)
+    loop = IngestLoop(rt, SnapshotRing(depth=4), publish_every=3,
+                      queue_depth=16, coalesce_max=2, feed_depth=2,
+                      tracer=tr)
+    for b in blocks[:8]:        # queued before the thread starts: one
+        loop.submit(b)          # wakeup stages several groups
+    loop.start()
+    loop.drain(timeout=60)
+    for b in blocks[8:]:
+        loop.submit(b)
+        time.sleep(0.01)
+    loop.stop()
+
+    spans = _spans(tr)
+    by_id = {e["id"]: e for e in spans}
+    assert {e["thread"] for e in spans} == {"repro-serve-ingest"}
+    steps = [e for e in spans if e["name"] == "ingest.step"]
+    stages = [e for e in spans if e["name"] == "ingest.stage"]
+    waits = [e for e in spans if e["name"] == "ingest.wait"]
+    assert steps and stages and waits
+    step_ids = {e["id"] for e in steps}
+    children = [e for e in spans if e["parent"] in step_ids]
+    assert children and {e["name"] for e in children} == {"ingest.stage"}
+    # the first staging of a wakeup runs before any step
+    assert any(e["depth"] == 0 for e in stages)
+    kinds = {k for k, _ in launched}
+    assert kinds == {"ingest", "merged"}
+    for kind, sid in launched:
+        want = "ingest.step" if kind == "ingest" else "ingest.publish"
+        assert by_id[sid]["name"] == want, (kind, by_id.get(sid))
+    for w in waits:
+        assert w["depth"] == 0
+        for s in steps:
+            assert (w["t"] + w["dur_s"] <= s["t"]
+                    or s["t"] + s["dur_s"] <= w["t"])
+
+
+def test_observer_spans_on_their_threads_and_none_with_metrics_off():
+    from repro.obs import trace as obs_trace
+    from repro.obs.trace import Tracer
+
+    rt = _runtime()
+    tr = Tracer(capacity=100_000)
+    cfg = _config(lazy_publish=False, sample_interval_s=0.02)
+    with ServingTier(cfg, runtime=rt, tracer=tr) as tier:
+        assert tier.health.tracer is tr and tier.sampler.tracer is tr
+        for b in _blocks(rt, 6):
+            tier.submit(b)
+        tier.drain()
+        deadline = time.perf_counter() + 30
+        while time.perf_counter() < deadline:
+            where = {(e["name"], e["thread"]) for e in _spans(tr)}
+            if {("ingest.observe.health", "repro-obs-health"),
+                    ("ingest.observe.drift", "repro-obs-health"),
+                    ("ingest.observe.tick", "repro-obs-sampler")} <= where:
+                break
+            time.sleep(0.02)
+        else:
+            pytest.fail(f"observer spans missing: {sorted(where)}")
+    spans = _spans(tr)
+    observe = [e for e in spans if e["name"].startswith("ingest.observe.")]
+    assert {e["name"] for e in observe} == {
+        "ingest.observe.health", "ingest.observe.drift",
+        "ingest.observe.tick"}
+    # health and drift are siblings: nothing of the tracer's nests in them
+    ids = {e["id"] for e in observe}
+    assert all(e["parent"] not in ids for e in spans)
+
+    off = Tracer(capacity=100_000)
+    with ServingTier(_config(metrics=False), runtime=rt,
+                     tracer=off) as tier:
+        for b in _blocks(rt, 4):
+            tier.submit(b)
+        tier.drain()
+    names = {e["name"] for e in _spans(off)}
+    assert "ingest.step" in names
+    assert not any(n.startswith("ingest.observe.") for n in names)
+    assert ServingTier(_config(metrics=False), runtime=rt).tracer \
+        is obs_trace.NULL
