@@ -27,7 +27,7 @@ from typing import Tuple
 
 import jax.numpy as jnp
 
-KERNELS = ("auto", "pallas", "jnp", "sorted", "fused")
+KERNELS = ("auto", "pallas", "jnp", "sorted", "fused", "sortjoin")
 FLUSH_MODES = ("deferred", "replay")
 
 # 'auto' resolution is owned by the PlanService (repro.plan): a measured,
@@ -48,7 +48,7 @@ class EngineConfig:
     buffer_depth: int = 8          # T — chunks buffered between merges
     flush_mode: str = "deferred"   # 'deferred' | 'replay'
     reduction: str = "local"       # key into the reduction registry
-    kernel: str = "auto"           # 'auto'|'pallas'|'jnp'|'sorted'|'fused'
+    kernel: str = "auto"           # 'auto' or an impl in KERNELS
     axis_names: Tuple[str, ...] = ()   # mesh axes for distributed reductions
     count_dtype: str = "int32"     # dtype name (kept as str: hashable)
     donate_state: bool = False     # donate the state arg of update/flush/

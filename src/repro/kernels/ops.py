@@ -4,10 +4,15 @@ Dispatch policy (``impl``):
   * ``'auto'``   — resolved through the active :mod:`repro.plan` plan
                    (``resolve_impl(op, k)``): a measured plan picks the
                    impl probed fastest on this backend; with no plan
-                   cached, the documented static fallback applies — Pallas
-                   on TPU, and off-TPU the pure-jnp reference below
-                   ``plan.SORTED_MIN_K`` counters with the sorted
-                   merge-join above it (``match_weights`` stays jnp).
+                   cached, the documented static fallback applies. On TPU
+                   that is Pallas, except the window flush
+                   (``ingest_window``) from ``plan.SORTJOIN_MIN_K``
+                   counters up, which runs ``'sortjoin'``: the dense
+                   kernel's k·T·C compares per lane cost more there than
+                   the join's two sorts of k + T·C entries. Off-TPU it is
+                   the pure-jnp reference below ``plan.SORTED_MIN_K``
+                   counters and the sorted merge-join above it
+                   (``match_weights`` stays jnp).
   * ``'pallas'`` — force the kernel: compiled by Mosaic on TPU, evaluated
                    in interpret mode on the CPU backend (tests); any other
                    backend raises instead of silently interpreting.
@@ -17,6 +22,11 @@ Dispatch policy (``impl``):
                    large k off-TPU. Requires distinct valid summary items
                    (true of every well-formed summary). Engine code selects
                    this centrally via EngineConfig.kernel (see repro.engine).
+  * ``'sortjoin'`` — merge-join by two ``lax.sort`` passes over summary ∪
+                   candidates (kernels/ref.py): no k×c intermediate, no
+                   scatter, no gather. Requires distinct valid ids on both
+                   sides, so ``query`` (whose batches may repeat ids)
+                   degrades it to ``'sorted'``.
   * ``'fused'``  — the whole-merge megakernel (kernels/ss_ingest.py): only
                    a real dispatch target for the window-level ops
                    (``ingest_window`` / ``combine_summaries``); at the
@@ -118,6 +128,10 @@ def match_weights(s_items: jax.Array, h_items: jax.Array, h_weights: jax.Array,
         impl = "sorted"      # the megakernel's internal matcher
     if impl == "sorted":
         return _ref.match_weights_sorted(s_items, h_items, h_weights)
+    if impl == "sortjoin":
+        add_w, _, _, matched = _ref.combine_match_sortjoin(
+            s_items, h_items, h_weights)
+        return add_w, matched
     if impl == "jnp":
         return _ref.match_weights_ref(s_items, h_items, h_weights)
     k, c = s_items.shape[0], h_items.shape[0]
@@ -149,7 +163,7 @@ def combine_match(s_items: jax.Array, c_items: jax.Array,
         impl = resolve_impl("combine", s_items.shape[0])
     if impl == "fused":
         impl = "sorted"      # the megakernel's internal matcher
-    if impl not in ("sorted", "jnp"):
+    if impl not in ("sorted", "jnp", "sortjoin"):
         # the Pallas kernel contracts in int32; wider count dtypes would
         # silently truncate, so route them to the (exact) sorted merge-join.
         wide = any(a is not None and jnp.dtype(a.dtype).itemsize > 4
@@ -158,6 +172,9 @@ def combine_match(s_items: jax.Array, c_items: jax.Array,
             impl = "sorted"
     if impl == "sorted":
         return _ref.combine_match_sorted(s_items, c_items, c_counts, c_errors)
+    if impl == "sortjoin":
+        return _ref.combine_match_sortjoin(s_items, c_items, c_counts,
+                                           c_errors)
     if impl == "jnp":
         return _ref.combine_match_ref(s_items, c_items, c_counts, c_errors)
     k, c = s_items.shape[0], c_items.shape[0]
@@ -187,8 +204,10 @@ def query(s_items, s_counts, s_errors, queries, *, impl: str = "auto",
     """
     if impl == "auto":
         impl = resolve_impl("query", s_items.shape[0])
-    if impl == "fused":
-        impl = "sorted"      # the megakernel's internal matcher
+    if impl in ("fused", "sortjoin"):
+        # the megakernel's internal matcher; the two-sort join needs
+        # distinct ids on both sides, and a query batch may repeat ids
+        impl = "sorted"
     if impl not in ("sorted", "jnp"):
         wide = any(jnp.dtype(a.dtype).itemsize > 4
                    for a in (s_counts, s_errors))

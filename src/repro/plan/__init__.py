@@ -18,14 +18,16 @@ caches a measured plan, and writes BENCH_plan.json.
 from repro.plan.fingerprint import cache_dir, device_fingerprint, plan_path
 from repro.plan.model import CostModel
 from repro.plan.plan import (PLAN_IMPLS, PLAN_OPS, SORTED_MIN_K,
-                             ExecutionPlan, static_impl, static_plan)
+                             SORTJOIN_MIN_K, ExecutionPlan, static_impl,
+                             static_plan)
 from repro.plan.service import (active_plan, clear, install,
                                 planned_engine_config, resolve_impl,
                                 resolve_reduction, use_plan)
 
 __all__ = [
-    "PLAN_IMPLS", "PLAN_OPS", "SORTED_MIN_K", "CostModel", "ExecutionPlan",
-    "active_plan", "cache_dir", "clear", "device_fingerprint", "install",
-    "plan_path", "planned_engine_config", "resolve_impl",
-    "resolve_reduction", "static_impl", "static_plan", "use_plan",
+    "PLAN_IMPLS", "PLAN_OPS", "SORTED_MIN_K", "SORTJOIN_MIN_K", "CostModel",
+    "ExecutionPlan", "active_plan", "cache_dir", "clear",
+    "device_fingerprint", "install", "plan_path", "planned_engine_config",
+    "resolve_impl", "resolve_reduction", "static_impl", "static_plan",
+    "use_plan",
 ]

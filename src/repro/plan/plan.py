@@ -42,13 +42,21 @@ PLAN_OPS = ("update", "combine", "query", "flush")
 #: 'fused' (kernels/ss_ingest.py) is measurement-only: static_impl never
 #: returns it — it reaches a table exclusively through a probe that timed
 #: it on the running backend (the paper's Xeon-vs-Phi discipline).
-PLAN_IMPLS = ("pallas", "jnp", "sorted", "fused")
+PLAN_IMPLS = ("pallas", "jnp", "sorted", "fused", "sortjoin")
 
 # the dense k×c match is near-quadratic in k; below this counter budget it
 # beats sort+searchsorted on CPU (measured in BENCH_sketch.json). This is
 # THE static fallback threshold — the former inline rule of kernels/ops.py
 # and EngineConfig, now owned by the plan layer.
 SORTED_MIN_K = 256
+
+# on TPU the flush's dense Pallas match does k·T·C compares per lane; the
+# two-sort merge-join (kernels/ref.py combine_match_sortjoin) costs two
+# sorts of k + T·C entries, nearly flat in k. On a TPU v5e at the plan's
+# window (T·C = 8 × 2048, 8 lanes) the two matches tie at 512 counters
+# (0.47 and 0.48 ms a flush) and the join wins above: 0.47 against 0.74 ms
+# at 1024, against 5.71 ms at 8000 (PERF.md §6).
+SORTJOIN_MIN_K = 512
 
 
 def _nearest_log(keys, x: int) -> int:
@@ -59,15 +67,19 @@ def _nearest_log(keys, x: int) -> int:
 def static_impl(op: str, k: int, *, on_tpu: bool | None = None) -> str:
     """The zero-measurement kernel heuristic (the pre-plan behavior).
 
-    TPU → the Pallas kernels control VMEM tiling; off-TPU the vectorized
-    jnp path wins at small k and the sorted merge-join past SORTED_MIN_K
-    for combine/query. ``update`` (match_weights) always takes the dense
-    jnp path off-TPU: its histogram side is small enough that the sort
-    never paid for itself in the seed measurements. ``flush`` (the
-    window-level merge) follows combine's rule — it is a combine-match
-    dispatched over the window histogram — and NEVER statically picks the
-    fused megakernel: its body contains sort/scatter/top_k, which only an
-    actual measurement can certify on a given backend.
+    TPU → the Pallas kernels control VMEM tiling, except the ``flush``
+    from SORTJOIN_MIN_K counters up: there the two-sort merge-join beats
+    the dense Pallas match on the chip (combine and query keep Pallas;
+    their candidate side is k ids or a query batch, not a window).
+    Off-TPU the vectorized jnp path wins at small k and the sorted
+    merge-join past SORTED_MIN_K for combine/query. ``update``
+    (match_weights) always takes the dense jnp path off-TPU: its
+    histogram side is small enough that the sort never paid for itself
+    in the seed measurements. Off-TPU ``flush`` (the window-level merge)
+    follows combine's rule — it is a combine-match dispatched over the
+    window histogram. No static rule picks the fused megakernel: its
+    body contains sort/scatter/top_k, which only an actual measurement
+    can certify on a given backend.
     """
     if op not in PLAN_OPS:
         raise ValueError(f"op {op!r} not in {PLAN_OPS}")
@@ -75,7 +87,8 @@ def static_impl(op: str, k: int, *, on_tpu: bool | None = None) -> str:
         import jax
         on_tpu = jax.default_backend() == "tpu"
     if on_tpu:
-        return "pallas"
+        return ("sortjoin" if op == "flush" and k >= SORTJOIN_MIN_K
+                else "pallas")
     if op == "update":
         return "jnp"
     return "sorted" if k >= SORTED_MIN_K else "jnp"

@@ -5,7 +5,9 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops
-from repro.kernels.ref import match_weights_ref, query_ref
+from repro.kernels.ref import (combine_match_ref, combine_match_sorted,
+                               combine_match_sortjoin, match_weights_ref,
+                               query_ref)
 
 SHAPES = [(8, 16), (100, 57), (512, 512), (1000, 300), (64, 2048), (2048, 64)]
 
@@ -106,7 +108,10 @@ def _mk_summary_batch(rng, b, k, fill):
     return tuple(jnp.asarray(a) for a in (items, counts, errors))
 
 
-@pytest.mark.parametrize("b,k,w", [(1, 64, 32), (3, 128, 256), (2, 300, 100)])
+INGEST_CASES = [(1, 64, 32), (3, 128, 256), (2, 300, 100)]
+
+
+@pytest.mark.parametrize("b,k,w", INGEST_CASES)
 def test_fused_ingest_kernel_vs_unfused(rng, b, k, w):
     from repro.kernels.ss_ingest import fused_ingest_pallas
     si, sc, se = _mk_summary_batch(rng, b, k, fill=0.6)
@@ -139,3 +144,91 @@ def test_fused_ingest_empty_window_is_top_k_identity(rng):
     ref = ops.ingest_window(si, sc, se, window, impl="sorted")
     for a, c in zip(out, ref):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+
+
+@pytest.mark.parametrize("b,k,w", INGEST_CASES)
+def test_ingest_window_sortjoin_vs_other_impls(rng, b, k, w):
+    """The two-sort flush equals the dense, sorted and Pallas flushes, on a
+    window whose last quarter is EMPTY (a partly filled buffer)."""
+    si, sc, se = _mk_summary_batch(rng, b, k, fill=0.6)
+    win = np.minimum(rng.zipf(1.2, size=(b, w)), 8 * k - 1).astype(np.int32)
+    win[:, 3 * w // 4:] = -1
+    window = jnp.asarray(win)
+    out = ops.ingest_window(si, sc, se, window, impl="sortjoin")
+    for other in ("jnp", "sorted", "pallas"):
+        ref = ops.ingest_window(si, sc, se, window, impl=other)
+        for name, a, c in zip(("items", "counts", "errors"), out, ref):
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(c),
+                err_msg=f"sortjoin vs {other} b={b} k={k} w={w} ch={name}")
+
+
+# ---------------------------------------------------------------------------
+# The two-sort merge-join matcher vs the dense and sorted matchers
+# ---------------------------------------------------------------------------
+
+MATCH_CASES = ["mixed", "padded", "empty_summary", "all_monitored",
+               "none_monitored"]
+
+
+def _mk_match_case(rng, k, w, case):
+    """A summary and a window histogram laid out as ``chunk_histogram``
+    emits it: an optional EMPTY slot first, distinct ids ascending, EMPTY
+    padding after. Valid ids are distinct on each side."""
+    universe = rng.choice(1 << 30, size=k + w, replace=False).astype(np.int32)
+    s_ids, fresh = universe[:k], universe[k:]
+    s_items = s_ids.copy()
+    if case == "empty_summary":
+        s_items[:] = -1
+    elif case != "all_monitored":
+        s_items[rng.random(k) < 0.2] = -1
+    n_valid = {"padded": max(1, w // 8)}.get(case, w - 1)
+    if case == "all_monitored":
+        ids = rng.choice(s_ids, size=min(n_valid, k), replace=False)
+    elif case in ("none_monitored", "empty_summary"):
+        ids = fresh[:n_valid]
+    else:                            # about half of the window monitored
+        hot = min(n_valid // 2, k // 2)
+        ids = np.concatenate([rng.choice(s_ids, size=hot, replace=False),
+                              fresh[:n_valid - hot]])
+    h_items = np.full(w, -1, np.int32)
+    h_items[1:1 + len(ids)] = np.sort(ids)
+    h_weights = (rng.integers(1, w + 1, w) * (h_items != -1)).astype(np.int32)
+    return tuple(map(jnp.asarray, (s_items, h_items, h_weights)))
+
+
+@pytest.mark.parametrize("k,w", [(64, 32), (300, 100), (2000, 16384),
+                                 (8000, 16384)])
+@pytest.mark.parametrize("case", MATCH_CASES)
+def test_sortjoin_match_bitwise_vs_dense_and_sorted(rng, k, w, case):
+    args = _mk_match_case(rng, k, w, case)
+    out = jax.jit(combine_match_sortjoin)(*args)
+    assert out[1] is None
+    for name, fn in (("dense", combine_match_ref),
+                     ("sorted", combine_match_sorted)):
+        ref = jax.jit(fn)(*args)
+        for ch, a, c in zip(("add_c", "add_e", "matched_s", "matched_c"),
+                            out, ref):
+            if c is None:
+                assert a is None
+                continue
+            assert a.dtype == c.dtype and a.shape == c.shape, (ch, name)
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(c),
+                err_msg=f"vs {name} k={k} w={w} case={case} out={ch}")
+    if case == "all_monitored":
+        assert int(np.asarray(out[3]).sum()) == int((args[1] != -1).sum())
+    if case in ("none_monitored", "empty_summary"):
+        assert not np.asarray(out[2]).any() and not np.asarray(out[3]).any()
+
+
+@pytest.mark.parametrize("k,c", [(64, 32), (300, 100), (100, 300)])
+def test_sortjoin_match_errors_channel(rng, k, c):
+    """Summary-vs-summary COMBINE carries errors: the join moves them with
+    the counts."""
+    si, ci, cc = _mk_match_case(rng, k, c, "mixed")
+    ce = cc // 3
+    out = combine_match_sortjoin(si, ci, cc, ce)
+    ref = combine_match_ref(si, ci, cc, ce)
+    for a, b in zip(out, ref):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

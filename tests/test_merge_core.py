@@ -33,7 +33,7 @@ from repro.engine import EngineConfig, SketchEngine
 from repro.kernels import ops
 from repro.kernels.ref import combine_match_ref
 
-ALL_IMPLS = ("jnp", "sorted", "pallas", "fused")
+ALL_IMPLS = ("jnp", "sorted", "pallas", "fused", "sortjoin")
 IMPLS = ((os.environ["REPRO_TEST_KERNEL"],)
          if os.environ.get("REPRO_TEST_KERNEL") else ALL_IMPLS)
 
@@ -181,7 +181,8 @@ def test_engine_resolved_kernel_reaches_reduction(monkeypatch):
     assert seen and set(seen) == {"sorted"}, seen
 
 
-@pytest.mark.parametrize("kernel", ["jnp", "sorted", "pallas", "fused"])
+@pytest.mark.parametrize("kernel", ["jnp", "sorted", "pallas", "fused",
+                                    "sortjoin"])
 def test_engine_merged_impls_agree(kernel):
     if kernel not in IMPLS and kernel != "jnp":
         pytest.skip(f"impl sweep restricted to {IMPLS}")

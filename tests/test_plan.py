@@ -61,6 +61,22 @@ def test_static_plan_reproduces_old_heuristics():
     assert plan.pods_for(8) == 1
 
 
+def test_static_tpu_flush_takes_the_join_from_its_crossover():
+    """On TPU the flush alone moves to the two-sort join at k at or above
+    the measured crossover; combine and query stay on the Pallas kernels."""
+    from repro.plan import SORTJOIN_MIN_K
+    assert static_impl("flush", 8000, on_tpu=True) == "sortjoin"
+    assert static_impl("flush", SORTJOIN_MIN_K, on_tpu=True) == "sortjoin"
+    assert static_impl("flush", SORTJOIN_MIN_K - 1, on_tpu=True) == "pallas"
+    for op in ("combine", "query"):
+        for k in (SORTJOIN_MIN_K - 1, 2000, 8000):
+            assert static_impl(op, k, on_tpu=True) == "pallas"
+    assert static_impl("flush", 8000, on_tpu=False) == "sorted"
+    ExecutionPlan(fingerprint="x", source="measured",
+                  kernels={"flush": {8000: "sortjoin"}}, reductions={},
+                  pods={})
+
+
 def test_plan_validation():
     with pytest.raises(ValueError, match="source"):
         ExecutionPlan(fingerprint="x", source="guessed", kernels={},

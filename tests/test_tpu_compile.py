@@ -90,3 +90,44 @@ def test_engine_ingest_step_compiles_at_paper_default(one_chip, on_tpu):
     block = jax.ShapeDtypeStruct((8, 2048), jnp.int32, sharding=one_chip)
     compiled = jax.jit(eng._ingest).lower(state, block).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k", [PAPER_K, 8000])
+def test_engine_ingest_step_compiles_with_static_tpu_flush(k, one_chip,
+                                                           on_tpu):
+    """One SketchEngine._ingest step at k = 2000 and 8000 (8 lanes, chunk
+    2048, depth 8) with the flush impl the static TPU plan resolves. The
+    two-sort join builds nothing of k × W elements and no Pallas call."""
+    import math
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.engine import EngineConfig, SketchEngine
+    from repro.plan import (PLAN_OPS, ExecutionPlan, static_impl,
+                            use_plan)
+
+    tpu_plan = ExecutionPlan(
+        fingerprint="v5e-rehearsal", source="static",
+        kernels={op: {k: static_impl(op, k, on_tpu=True)} for op in PLAN_OPS},
+        reductions={}, pods={})
+    with use_plan(tpu_plan):
+        eng = SketchEngine(EngineConfig(k=k, tenants=8, chunk=2048,
+                                        buffer_depth=8))
+        flush = eng.config.resolved_flush_kernel()
+        state = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            eng.state_shapes())
+        block = jax.ShapeDtypeStruct((8, 2048), jnp.int32, sharding=one_chip)
+        text = jax.jit(eng._ingest).lower(state, block).compile().as_text()
+    assert flush == static_impl("flush", k, on_tpu=True)
+    if flush == "pallas":
+        assert "tpu_custom_call" in text
+        return
+    assert flush == "sortjoin"
+    assert "tpu_custom_call" not in text
+    largest = max(math.prod(int(d) for d in dims.split(",") if d)
+                  for dims in re.findall(r"\b[a-z]+\d*\[([\d,]*)\]", text))
+    assert 8 * (k + WINDOW) <= largest < k * WINDOW, largest
