@@ -202,7 +202,7 @@ class SketchEngine:
 
     # -- queries ------------------------------------------------------------
 
-    def _merged(self, state: SketchState) -> Summary:
+    def _merged(self, state: SketchState, axes=None) -> Summary:
         """One global summary: flush view, then the reduction strategy.
 
         Device-resident cheap path (DESIGN.md §13): when ``fill == 0``
@@ -215,8 +215,12 @@ class SketchEngine:
         changes a summary; asserted per kernel × flush mode in
         tests/test_serve.py). Traced under the ``sketch.merge`` name
         scope (the publish merge of ``StreamRuntime.merged``).
+
+        ``axes`` replaces the configured mesh axes; ``()`` stops at the
+        lane reduce, which is the sharded runtime's first publish program
+        (its second, the exchange, runs the strategy across the mesh).
         """
-        axes = tuple(self.config.axis_names)
+        axes = tuple(self.config.axis_names if axes is None else axes)
         with jax.named_scope("sketch.merge"):
             return lax.cond(
                 state.fill == 0,

@@ -49,12 +49,20 @@ from repro.core.spacesaving import Summary
 from repro.engine import SketchEngine
 from repro.engine.state import SketchState
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.runtime.config import RuntimeConfig
 from repro.runtime.feed import DeviceFeed, host_blocks
 
 # batch feed()'s time-gated history pump (DESIGN.md §14): at most one
 # registry sample per interval, regardless of block rate
 FEED_SAMPLE_INTERVAL_S = 0.25
+
+
+def _rank0(tree):
+    """Each leaf's buffer on the mesh's first device (rank 0's row of a
+    dim-0 sharded array, or that device's copy of a replicated one): no
+    program runs and nothing waits."""
+    return jax.tree.map(lambda a: a.addressable_data(0), tree)
 
 
 class StreamRuntime:
@@ -173,24 +181,46 @@ class StreamRuntime:
         # donated twin for feed()'s loop (see single-shard branch)
         self._feed_ingest_fn = jax.jit(ingest_blocks, donate_argnums=(0,))
 
-        def shard_merged(summary, buffer, n, fill):
+        # the publish is two programs, so that a profile can tell the
+        # exchange between chips from the work each chip does alone:
+        # _lane_reduce (flush view + local lane reduce, one summary a shard,
+        # left sharded), then _exchange (the mesh reduction strategy over
+        # those rows; every rank ends with the global summary, each keeps
+        # its own row). Together they evaluate exactly what the strategy
+        # does in one pass: on a one-row stack its lane tree is the
+        # identity. Rank 0's row is then taken as its device's buffer, with
+        # no further program: a published summary lives on one device, so
+        # the reads' kernels run there unpartitioned (Mosaic kernels
+        # cannot be partitioned across a mesh).
+        row_specs = Summary(spec1, spec1, spec1)
+
+        def shard_lane_reduce(summary, buffer, n, fill):
             st = SketchState(summary=summary, buffer=buffer, fill=fill, n=n)
-            # flush view + local lane reduce + mesh reduction strategy; all
-            # ranks end with the same global summary — stack and read rank 0
-            merged = eng._merged(st)
-            return jax.tree.map(lambda a: a[None], merged)
+            return jax.tree.map(lambda a: a[None], eng._merged(st, axes=()))
 
-        smap_merged = jax.shard_map(
-            shard_merged, mesh=self.mesh,
-            in_specs=state_specs + (P(),),
-            out_specs=Summary(spec1, spec1, spec1), check_vma=False)
+        smap_lane_reduce = jax.shard_map(
+            shard_lane_reduce, mesh=self.mesh,
+            in_specs=state_specs + (P(),), out_specs=row_specs,
+            check_vma=False)
 
-        def merged(state: SketchState) -> Summary:
-            stacked = smap_merged(state.summary, state.buffer, state.n,
-                                  state.fill)
-            return jax.tree.map(lambda a: a[0], stacked)
+        def shard_exchange(rows: Summary) -> Summary:
+            return eng._reduce(rows, self._axes)
 
-        self._merged_fn = jax.jit(merged)
+        smap_exchange = jax.shard_map(
+            shard_exchange, mesh=self.mesh, in_specs=(row_specs,),
+            out_specs=row_specs, check_vma=False)
+
+        def _lane_reduce(state: SketchState) -> Summary:
+            return smap_lane_reduce(state.summary, state.buffer, state.n,
+                                    state.fill)
+
+        def _exchange(rows: Summary) -> Summary:
+            return smap_exchange(rows)
+
+        self._lane_reduce_fn = jax.jit(_lane_reduce)
+        self._exchange_fn = jax.jit(_exchange)
+        self._merged_fn = lambda state: _rank0(self._exchange_fn(
+            self._lane_reduce_fn(state)))
 
     # -- state construction --------------------------------------------------
 
@@ -300,12 +330,14 @@ class StreamRuntime:
     # -- reads -----------------------------------------------------------------
 
     def merged(self, state: SketchState) -> Summary:
-        """One global summary: flush view → lane reduce → mesh reduction."""
+        """One global summary: flush view → lane reduce → mesh reduction
+        (on a mesh, the lane-reduce program, then the exchange program)."""
         return self._merged_fn(state)
 
     def snapshot(self, state: SketchState, *, lazy: bool = False,
                  version: int | None = None, n_hint: int | None = None,
-                 on_materialize=None):
+                 on_materialize=None, tracer=obs_trace.NULL,
+                 on_exchange=None):
         """Publish an immutable versioned QuerySnapshot (QueryService handoff).
 
         Provenance carries the per-WORKER ingest counts ((W,) — the paper's
@@ -318,23 +350,38 @@ class StreamRuntime:
         ``SketchEngine.snapshot``); the caller owes the donation fence —
         ``state`` must never later be donated (``feed()`` donates its
         loop-internal states, so a published caller-held state is safe).
+
+        On a mesh the exchange program launches directly inside
+        ``tracer``'s ``ingest.exchange`` span, and ``on_exchange()`` is
+        called once it is launched; a lazy snapshot does both when a reader
+        materializes it, on that reader's thread. One shard has no exchange.
         """
-        from repro.service.snapshot import publish, publish_lazy
+        from repro.service.snapshot import publish_lazy
         if version is None:
             version = next(self._versions)
         obs_metrics.DEFAULT.counter("runtime.snapshot_publishes").inc()
         if lazy:
             c = self.engine.config
             return publish_lazy(
-                lambda: self._eager_snapshot(state, version),
+                lambda: self._eager_snapshot(state, version, tracer,
+                                             on_exchange),
                 version=version, kernel=c.resolved_kernel(), k=c.k,
                 n_hint=n_hint, on_materialize=on_materialize)
-        return self._eager_snapshot(state, version)
+        return self._eager_snapshot(state, version, tracer, on_exchange)
 
-    def _eager_snapshot(self, state: SketchState, version: int):
+    def _eager_snapshot(self, state: SketchState, version: int,
+                        tracer=obs_trace.NULL, on_exchange=None):
         from repro.service.snapshot import publish
-        summary = self._merged_fn(state)
-        return publish(summary, state.n.sum(), state.n, version=version,
+        if self.mesh is None:
+            summary, n = self._merged_fn(state), state.n.sum()
+        else:
+            rows = self._lane_reduce_fn(state)
+            with tracer.span("ingest.exchange"):
+                summary = _rank0(self._exchange_fn(rows))
+            if on_exchange is not None:
+                on_exchange()
+            n = _rank0(state.n.sum())
+        return publish(summary, n, state.n, version=version,
                        kernel=self.engine.config.resolved_kernel())
 
     def frontend(self):

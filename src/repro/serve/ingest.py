@@ -199,6 +199,8 @@ class IngestLoop:
         self._m_coalesce = reg.histogram("serve.ingest.coalesce_blocks")
         self._m_deferred = reg.counter("serve.publish.deferred")
         self._m_materialized = reg.counter("serve.publish.materialized")
+        # launches of the exchange between chips (0 on one shard)
+        self._m_exchanges = reg.counter("serve.publish.exchanges")
         # invoked (once, from the loop thread) with the captured
         # exception — the flight recorder's ingest-error dump trigger
         self.on_error = on_error
@@ -461,13 +463,15 @@ class IngestLoop:
         # materialization). Lazy publishes capture the state reference +
         # the writer's own item count (the count_floor ε filter) and ring
         # immediately; the materialized counter tells the bench how many
-        # versions a reader ever actually forced.
+        # versions a reader ever actually forced. On a mesh the exchange
+        # program launches in an ``ingest.exchange`` span nested here.
         lazy = self.lazy_publish
         with self.tracer.span("ingest.publish"):
             snap = self._publisher.publish(
                 self._state, lazy=lazy,
                 n_hint=self.stats.items_ingested if lazy else None,
-                on_materialize=self._m_materialized.inc if lazy else None)
+                on_materialize=self._m_materialized.inc if lazy else None,
+                tracer=self.tracer, on_exchange=self._m_exchanges.inc)
         if lazy:
             self._m_deferred.inc()
         self.stats.add(publishes=1)

@@ -45,6 +45,7 @@ from __future__ import annotations
 import collections
 import threading
 
+from repro.obs import trace as obs_trace
 from repro.service.snapshot import QuerySnapshot
 
 
@@ -155,14 +156,16 @@ class RingPublisher:
         self.ring = ring
 
     def publish(self, state, *, lazy: bool = False,
-                n_hint: int | None = None,
-                on_materialize=None) -> QuerySnapshot:
+                n_hint: int | None = None, on_materialize=None,
+                tracer=obs_trace.NULL, on_exchange=None) -> QuerySnapshot:
         """Snapshot ``state`` (async dispatch; ingest-safe) and ring it.
 
         ``lazy=True`` publishes a deferred snapshot (reduction on first
         read); the caller owes the donation fence on ``state`` — see
-        ``StreamRuntime.snapshot``.
+        ``StreamRuntime.snapshot``. On a mesh the exchange between chips
+        runs in ``tracer``'s ``ingest.exchange`` span and ``on_exchange``
+        counts it.
         """
         return self.ring.publish(self.runtime.snapshot(
-            state, lazy=lazy, n_hint=n_hint,
-            on_materialize=on_materialize))
+            state, lazy=lazy, n_hint=n_hint, on_materialize=on_materialize,
+            tracer=tracer, on_exchange=on_exchange))

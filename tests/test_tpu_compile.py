@@ -131,3 +131,64 @@ def test_engine_ingest_step_compiles_with_static_tpu_flush(k, one_chip,
     largest = max(math.prod(int(d) for d in dims.split(",") if d)
                   for dims in re.findall(r"\b[a-z]+\d*\[([\d,]*)\]", text))
     assert 8 * (k + WINDOW) <= largest < k * WINDOW, largest
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip, topo):
+    """The described v5e:2x2's 4 devices (after ``one_chip``, which turns
+    the persistent compilation cache off)."""
+    return topo.devices[:4]
+
+
+def test_sharded_publish_and_ingest_compile_for_four_chips(four_chips,
+                                                           on_tpu,
+                                                           monkeypatch):
+    """The paper's hybrid deployment at k = 2000: 4 shards × 8 lanes, chunk
+    2048, depth 8, with the static TPU plan. The ingest and both publish
+    programs compile for the 4 devices; the COMBINEs of the publish are
+    Pallas calls; a collective-permute (the butterfly's ``ppermute``) is
+    in the exchange program and in no other. The runtime builds its mesh
+    from ``jax.devices()``, steered here to the described devices."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.spacesaving import Summary
+    from repro.engine import EngineConfig
+    from repro.plan import (PLAN_OPS, ExecutionPlan, static_impl,
+                            use_plan)
+    from repro.runtime import RuntimeConfig, StreamRuntime
+
+    devices = list(four_chips)
+    make_mesh = jax.make_mesh
+    monkeypatch.setattr(jax, "devices", lambda *a, **kw: devices)
+    monkeypatch.setattr(jax, "make_mesh", lambda shape, axes, **kw:
+                        make_mesh(shape, axes, **{**kw, "devices": devices}))
+    tpu_plan = ExecutionPlan(
+        fingerprint="v5e-rehearsal", source="static",
+        kernels={op: {PAPER_K: static_impl(op, PAPER_K, on_tpu=True)}
+                 for op in PLAN_OPS},
+        reductions={}, pods={})
+    with use_plan(tpu_plan):
+        rt = StreamRuntime(RuntimeConfig(
+            engine=EngineConfig(k=PAPER_K, tenants=8, chunk=2048,
+                                buffer_depth=8),
+            shards=4, reduction="auto"))
+        assert rt.engine.config.reduction == "butterfly"
+        state = jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            jax.eval_shape(rt.init), rt.state_shardings())
+        block = jax.ShapeDtypeStruct((32, 2048), jnp.int32,
+                                     sharding=rt.block_sharding())
+        row = jax.ShapeDtypeStruct((4, PAPER_K), jnp.int32,
+                                   sharding=rt.block_sharding())
+        texts = {
+            "ingest": rt._ingest_blocks_fn.lower(state, block),
+            "lane_reduce": rt._lane_reduce_fn.lower(state),
+            "exchange": rt._exchange_fn.lower(Summary(row, row, row)),
+        }
+        texts = {k: v.compile().as_text() for k, v in texts.items()}
+    for name, text in texts.items():
+        assert "num_partitions=4" in text, name
+        assert ("collective-permute" in text) == (name == "exchange"), name
+    assert "tpu_custom_call" in texts["lane_reduce"]
+    assert "tpu_custom_call" in texts["exchange"]
