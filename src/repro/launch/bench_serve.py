@@ -26,7 +26,8 @@ jitted programs, so phases compare compute, not compiles):
 A fifth, reader-free **pipeline** phase (DESIGN.md §13) runs a shortened
 stream through the legacy serving discipline (one block per dispatch, no
 staging overlap, eager publishes) and through the tuned async pipeline
-(plan-resolved ``coalesce_max`` / ``feed_depth`` / ``lazy_publish``) —
+(groups up to the publish boundary, plan-resolved ``feed_depth`` /
+``lazy_publish``) —
 same host, same run, same jitted programs — and records the throughput
 ``gain`` per impl. ``--budget-s`` caps each phase's stream from a warmed
 per-block measurement so the whole run fits a time budget without
@@ -210,7 +211,7 @@ def _run_tier(runtime, blocks, *, publish_every, ring_depth, queue_depth,
 
 def run_bench(*, impls, k, lanes, chunk, depth, blocks, layers,
               publish_every, ring_depth, queue_depth, admission, readers,
-              qps, kmaj, coalesce_max=1, feed_depth=2, lazy_publish=False,
+              qps, kmaj, coalesce_max=None, feed_depth=2, lazy_publish=False,
               budget_s=None, pipeline_blocks=96,
               pipeline_coalesce_max=None, pipeline_feed_depth=None,
               pipeline_lazy=None, seed=0,
@@ -221,7 +222,7 @@ def run_bench(*, impls, k, lanes, chunk, depth, blocks, layers,
     from repro.data.synthetic import zipf_stream
     from repro.engine import EngineConfig
     from repro.runtime import RuntimeConfig, StreamRuntime
-    from repro.runtime.feed import coalesce_blocks, host_blocks
+    from repro.runtime.feed import host_blocks
 
     results = {}
     for impl in impls:
@@ -275,7 +276,9 @@ def run_bench(*, impls, k, lanes, chunk, depth, blocks, layers,
         emit(f"serve_{impl}_lazy_eager_equiv", str(lazy_ok).lower(),
              f"version={lazy_snap.version}")
 
-        # 2. warmup tier: compile donated ingest + publish + query paths
+        # 2. warmup tier: compile publish + query paths (every tier's
+        # start() compiles the ingest programs for each group shape its
+        # loop may dispatch, so no timed phase below compiles one)
         _run_tier(rt, host_stream[:2], publish_every=publish_every,
                   ring_depth=ring_depth, queue_depth=queue_depth,
                   admission=admission, queries=queries, kmaj=kmaj,
@@ -289,19 +292,6 @@ def run_bench(*, impls, k, lanes, chunk, depth, blocks, layers,
         pipe_f = (feed_depth if pipeline_feed_depth is None
                   else pipeline_feed_depth)
         pipe_l = lazy_publish if pipeline_lazy is None else pipeline_lazy
-
-        # 2b. warm every coalesced group shape the loop may dispatch
-        # (1..cap blocks, both ingest twins) — queue dynamics decide the
-        # batch sizes at runtime, and a mid-phase compile would be
-        # charged to the timed arm that first hit that shape
-        cap = max(1, min(max(coalesce_max, pipe_c), publish_every))
-        if cap > 1:
-            wstate = rt.init()
-            for m in range(1, cap + 1):
-                blk = coalesce_blocks(host_stream[:m], rt.workers, chunk)
-                wstate = rt._ingest_blocks_fn(wstate, blk)
-                wstate = rt._feed_ingest_fn(wstate, blk)
-            jax.block_until_ready(wstate.summary.counts)
 
         # 3. reader-free baseline
         base = _run_tier(rt, host_stream, publish_every=publish_every,
@@ -561,7 +551,8 @@ def main(argv=None) -> int:
                     help="snapshot ring depth (default: active plan)")
     ap.add_argument("--coalesce-max", type=int, default=None,
                     help="max queued blocks per coalesced ingest dispatch "
-                         "(default: active plan)")
+                         "(default: publish_every, up to the publish "
+                         "boundary)")
     ap.add_argument("--feed-depth", type=int, default=None,
                     help="host→device staging depth (default: active plan)")
     ap.add_argument("--lazy-publish", default="auto",
@@ -634,7 +625,8 @@ def main(argv=None) -> int:
     plan = active_plan()
     publish_every = args.publish_every or plan.publish_every
     ring_depth = args.ring_depth or plan.ring_depth
-    coalesce_max = args.coalesce_max or plan.coalesce_max
+    # the group width is not planned: groups run up to the publish boundary
+    coalesce_max = args.coalesce_max or publish_every
     feed_depth = args.feed_depth or plan.feed_depth
     lazy_publish = (plan.lazy_publish if args.lazy_publish == "auto"
                     else args.lazy_publish == "on")
@@ -646,7 +638,8 @@ def main(argv=None) -> int:
 
     emit("serve_publish_every", publish_every, f"plan={plan.source}")
     emit("serve_ring_depth", ring_depth, f"plan={plan.source}")
-    emit("serve_coalesce_max", coalesce_max, f"plan={plan.source}")
+    emit("serve_coalesce_max", coalesce_max,
+         "flag" if args.coalesce_max else "publish_every")
     emit("serve_feed_depth", feed_depth, f"plan={plan.source}")
     emit("serve_lazy_publish", str(lazy_publish).lower(),
          f"plan={plan.source}")

@@ -53,8 +53,10 @@ OPS = ("combine", "query", "flush")
 # write-path pair (one ingest step vs one snapshot publish) and the plan
 # records a CADENCE (publish_every / ring_depth), not an impl choice — so
 # it is handled outside the kernel sweep/gate machinery below. 'pipeline'
-# likewise: it measures the async-ingestion knobs (coalesce_max /
-# feed_depth / lazy_publish, DESIGN.md §13) on the serving hot loop.
+# likewise: it measures the async-ingestion knobs (feed_depth /
+# lazy_publish, DESIGN.md §13) on the serving hot loop. The coalesce width
+# is not a plan knob: the serving loop groups queued blocks up to the
+# publish boundary, and on the chip each larger width ingested faster.
 DEFAULT_OPS = OPS + ("publish", "pipeline")
 STRATEGIES = ("butterfly", "allgather", "hierarchical")
 
@@ -88,8 +90,8 @@ def _choose_publish(rows, budget: float = PUBLISH_BUDGET) -> tuple[int, int]:
     return publish_every, ring_depth
 
 
-#: a pipeline knob value within this fraction of the best probed cell is
-#: "as good": the SMALLEST such value wins (less queueing delay / memory)
+#: a staging depth within this fraction of the best probed cell is "as
+#: good": the SMALLEST such depth wins (less device memory)
 PIPELINE_SLACK = 0.02
 
 #: lazy publishing pays off once an eager publish costs more than this
@@ -98,23 +100,17 @@ PIPELINE_SLACK = 0.02
 LAZY_PUBLISH_MIN_RATIO = 0.05
 
 
-def _choose_pipeline(rows) -> tuple[int, int, bool]:
-    """(coalesce_max, feed_depth, lazy_publish) from the pipeline probes.
+def _choose_pipeline(rows) -> tuple[int, bool]:
+    """(feed_depth, lazy_publish) from the pipeline probes.
 
-    Coalescing and staging depth both trade latency/memory for amortized
-    dispatch overhead, so each knob takes the SMALLEST probed value whose
-    per-block cost is within ``PIPELINE_SLACK`` of the best cell — past
-    the flattening point, more coalescing only adds queueing delay.
-    ``lazy_publish`` turns on when the measured eager publish is a
-    non-trivial fraction of one ingest step (the deferral then removes
-    real write-path work for every never-read version).
+    Staging depth trades device memory for overlapped transfers, so it
+    takes the SMALLEST probed depth whose per-block cost is within
+    ``PIPELINE_SLACK`` of the best cell. ``lazy_publish`` turns on when
+    the measured eager publish is a non-trivial fraction of one ingest
+    step (the deferral then removes real write-path work for every
+    never-read version).
     """
-    coalesce_max, feed_depth, lazy = 1, 2, False
-    co = {r["m"]: r["block_s"] for r in rows if r.get("knob") == "coalesce"}
-    if co:
-        best = min(co.values())
-        coalesce_max = min(m for m, t in co.items()
-                           if t <= (1.0 + PIPELINE_SLACK) * best)
+    feed_depth, lazy = 2, False
     fe = {r["depth"]: r["block_s"] for r in rows if r.get("knob") == "feed"}
     if fe:
         best = min(fe.values())
@@ -125,7 +121,7 @@ def _choose_pipeline(rows) -> tuple[int, int, bool]:
         r = pub[-1]
         lazy = r["eager_s"] > LAZY_PUBLISH_MIN_RATIO * max(r["step_s"],
                                                            1e-12)
-    return int(coalesce_max), int(feed_depth), bool(lazy)
+    return int(feed_depth), bool(lazy)
 
 
 def _impls_for_op(op: str, impls) -> list[str]:
@@ -485,21 +481,19 @@ def main(argv=None) -> int:
         publish_every, ring_depth = _choose_publish(publish_rows)
 
     # -- pipeline probes (async-ingestion knobs) -----------------------------
-    # coalesce width / staging depth / lazy-vs-eager publish on the same
-    # single-shard serving hot loop, folded into the plan's pipeline knobs
+    # staging depth / lazy-vs-eager publish on the same single-shard
+    # serving hot loop, folded into the plan's pipeline knobs
     pipeline_rows = []
-    coalesce_max, feed_depth, lazy_publish = 1, 2, False
+    feed_depth, lazy_publish = 2, False
     if "pipeline" in ops:
         impl_pipe = kernels.get("combine", {}).get(
             max(ks), static_impl("combine", max(ks)))
         pipeline_rows = probe_pipeline(
             k=max(ks), lanes=args.lanes, chunk=chunk,
             depth=min(args.depth, 4), impl=impl_pipe,
-            coalesce=(1, 2, 4) if q else (1, 2, 4, 8),
             feed_depths=(1, 2) if q else (1, 2, 4),
             repeat=args.repeat, seed=args.seed, emit=emit)
-        coalesce_max, feed_depth, lazy_publish = \
-            _choose_pipeline(pipeline_rows)
+        feed_depth, lazy_publish = _choose_pipeline(pipeline_rows)
 
     # -- materialize ---------------------------------------------------------
     plan = ExecutionPlan(
@@ -507,8 +501,7 @@ def main(argv=None) -> int:
         reductions=reductions, pods=pods, chunk=chunk,
         buffer_depth=args.depth, query_min_batch=min_batch,
         publish_every=publish_every, ring_depth=ring_depth,
-        coalesce_max=coalesce_max, feed_depth=feed_depth,
-        lazy_publish=lazy_publish)
+        feed_depth=feed_depth, lazy_publish=lazy_publish)
     for op in kernel_ops:
         emit(f"plan_{op}", " ".join(f"k{k}:{v}"
                                     for k, v in sorted(kernels[op].items())))
@@ -517,9 +510,7 @@ def main(argv=None) -> int:
     emit("plan_publish_every", publish_every,
          f"budget={PUBLISH_BUDGET:.0%}")
     emit("plan_ring_depth", ring_depth)
-    emit("plan_coalesce_max", coalesce_max,
-         f"slack={PIPELINE_SLACK:.0%}")
-    emit("plan_feed_depth", feed_depth)
+    emit("plan_feed_depth", feed_depth, f"slack={PIPELINE_SLACK:.0%}")
     emit("plan_lazy_publish", str(lazy_publish).lower(),
          f"min_ratio={LAZY_PUBLISH_MIN_RATIO:.0%}")
     for p, s in sorted(reductions.items()):

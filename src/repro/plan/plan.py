@@ -108,7 +108,6 @@ class ExecutionPlan:
     query_min_batch: int = 16                # QueryFrontend bucket floor
     publish_every: int = 8                   # serving: blocks per ring publish
     ring_depth: int = 4                      # serving: SnapshotRing slots
-    coalesce_max: int = 1                    # serving: max blocks per dispatch
     feed_depth: int = 2                      # host→device staging slots
     lazy_publish: bool = False               # serving: defer snapshot reduce
     format: int = PLAN_FORMAT
@@ -138,10 +137,9 @@ class ExecutionPlan:
             raise ValueError(
                 f"publish_every/ring_depth must be positive: "
                 f"{self.publish_every}/{self.ring_depth}")
-        if self.coalesce_max < 1 or self.feed_depth < 1:
+        if self.feed_depth < 1:
             raise ValueError(
-                f"coalesce_max/feed_depth must be >= 1: "
-                f"{self.coalesce_max}/{self.feed_depth}")
+                f"feed_depth must be >= 1, got {self.feed_depth}")
         if not isinstance(self.lazy_publish, bool):
             raise ValueError(
                 f"lazy_publish must be a bool, got {self.lazy_publish!r}")
@@ -189,7 +187,6 @@ class ExecutionPlan:
             "query_min_batch": self.query_min_batch,
             "publish_every": self.publish_every,
             "ring_depth": self.ring_depth,
-            "coalesce_max": self.coalesce_max,
             "feed_depth": self.feed_depth,
             "lazy_publish": self.lazy_publish,
         }
@@ -216,8 +213,9 @@ class ExecutionPlan:
             publish_every=int(d.get("publish_every", 8)),
             ring_depth=int(d.get("ring_depth", 4)),
             # pipeline knobs arrived with DESIGN.md §13; legacy defaults
-            # reproduce the pre-pipeline serving discipline exactly
-            coalesce_max=int(d.get("coalesce_max", 1)),
+            # reproduce the pre-pipeline serving discipline. A
+            # ``coalesce_max`` of older plans is ignored: the serving loop
+            # groups blocks up to the publish boundary
             feed_depth=int(d.get("feed_depth", 2)),
             lazy_publish=bool(d.get("lazy_publish", False)),
         )

@@ -16,7 +16,7 @@ Rows are plain dicts (JSON-ready for BENCH_plan.json):
   reduction probes  {strategy, p, pods, k, time_s}
   publish probes    {op: "publish", k, lanes, chunk, step_s, publish_s,
                      publish_per_step}
-  pipeline probes   {op: "pipeline", knob: "coalesce"|"feed"|"publish", ...}
+  pipeline probes   {op: "pipeline", knob: "feed"|"publish", ...}
 """
 from __future__ import annotations
 
@@ -225,17 +225,13 @@ def probe_publish(*, ks=(256, 2048), lanes: int = 4, chunk: int = 2048,
 
 def probe_pipeline(*, k: int = 2048, lanes: int = 4, chunk: int = 2048,
                    depth: int = 4, impl: str = "auto",
-                   coalesce=(1, 2, 4, 8), feed_depths=(1, 2, 4),
+                   feed_depths=(1, 2, 4),
                    repeat: int = 3, seed: int = 0,
                    emit=lambda *a: None) -> list[dict]:
     """The asynchronous-pipeline knobs, measured on the serving hot loop.
 
-    Three sub-probes on one warmed single-shard runtime (DESIGN.md §13):
+    Two sub-probes on one warmed single-shard runtime (DESIGN.md §13):
 
-      knob="coalesce"  per-block amortized cost of ingesting m canonical
-                       blocks as ONE coalesced (W, m·chunk) dispatch —
-                       where the dispatch-overhead amortization flattens
-                       out is the plan's ``coalesce_max``
       knob="feed"      per-block cost of the feed() loop at each staging
                        depth (the double-buffering payoff curve) —
                        smallest depth within noise of the best wins
@@ -246,7 +242,6 @@ def probe_pipeline(*, k: int = 2048, lanes: int = 4, chunk: int = 2048,
     from repro.data.synthetic import zipf_stream
     from repro.engine import EngineConfig
     from repro.runtime import RuntimeConfig, StreamRuntime
-    from repro.runtime.feed import coalesce_blocks
 
     rt = StreamRuntime(RuntimeConfig(
         engine=EngineConfig(k=k, tenants=lanes, chunk=chunk,
@@ -257,15 +252,6 @@ def probe_pipeline(*, k: int = 2048, lanes: int = 4, chunk: int = 2048,
     state = rt.ingest(rt.init(), warm)
 
     rows = []
-    payloads = [zipf_stream(rt.workers * chunk, 1.1, seed=seed + 31 + i,
-                            max_id=10**6) for i in range(max(coalesce))]
-    for m in sorted(set(int(m) for m in coalesce if m >= 1)):
-        block = coalesce_blocks(payloads[:m], rt.workers, chunk)
-        t = timeit(rt.ingest, state, block, repeat=repeat) / m
-        rows.append({"op": "pipeline", "knob": "coalesce", "m": int(m),
-                     "k": int(k), "chunk": int(chunk), "block_s": t})
-        emit(f"probe_pipeline_coalesce_m{m}", f"{t:.4e}")
-
     n_blocks = 8
     feed_payloads = [zipf_stream(rt.workers * chunk, 1.1,
                                  seed=seed + 61 + i, max_id=10**6)
@@ -282,7 +268,8 @@ def probe_pipeline(*, k: int = 2048, lanes: int = 4, chunk: int = 2048,
                      "k": int(k), "block_s": t})
         emit(f"probe_pipeline_feed_d{d}", f"{t:.4e}")
 
-    block = rt.decompose(payloads[0])
+    block = rt.decompose(zipf_stream(rt.workers * chunk, 1.1,
+                                     seed=seed + 31, max_id=10**6))
     step_s = timeit(rt.ingest, state, block, repeat=repeat)
     eager_s = timeit(lambda: rt.snapshot(state).summary, repeat=repeat)
     rows.append({"op": "pipeline", "knob": "publish", "k": int(k),

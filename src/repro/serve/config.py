@@ -47,8 +47,9 @@ class ServeConfig:
                                        # None → the active plan's cadence
     ring_depth: int | None = None      # SnapshotRing slots; None → plan
     coalesce_max: int | None = None    # max queued blocks ingested as ONE
-                                       # coalesced dispatch; None → plan
-                                       # (static fallback 1 — per-block)
+                                       # coalesced dispatch; None → the
+                                       # resolved publish_every (groups
+                                       # run up to the publish boundary)
     lazy_publish: bool | None = None   # defer the snapshot reduction to
                                        # the first reader; None → plan
                                        # (static fallback False — eager)
@@ -129,11 +130,11 @@ class ServeConfig:
         return active_plan().ring_depth
 
     def resolved_coalesce_max(self) -> int:
-        """Max blocks per coalesced ingest dispatch (None → plan)."""
+        """Max blocks per coalesced ingest dispatch (None → up to the
+        publish boundary: the resolved publish_every)."""
         if self.coalesce_max is not None:
             return self.coalesce_max
-        from repro.plan import active_plan
-        return active_plan().coalesce_max
+        return self.resolved_publish_every()
 
     def resolved_lazy_publish(self) -> bool:
         """Whether ring publishes defer their reduction (None → plan)."""

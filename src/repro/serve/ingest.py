@@ -15,14 +15,22 @@ Throughput discipline, in order of importance (DESIGN.md §11, §13):
     pointer immediately — or, with ``lazy_publish``, not dispatching it
     at all until a reader asks; readers materialize their own answers.
   * **wakeups drain, dispatches coalesce.** Each wakeup drains every
-    consecutively queued block (up to a control item), groups them into
-    at most ``coalesce_max``-block batches, and ingests each batch as
-    ONE jitted dispatch over the concatenated canonical decomposition —
-    bitwise-identical to per-block ingestion (the engine scans chunks in
-    order; ``coalesce_blocks``) while paying the Python/dispatch
-    overhead once per batch. Groups never straddle a publish boundary,
-    so the publish cadence (positions AND count) is exactly the
-    per-block loop's.
+    consecutively queued block (up to a control item), cuts them into
+    runs of at most ``coalesce_max`` blocks, and ingests each group as
+    ONE transfer and ONE jitted dispatch over the concatenated canonical
+    decomposition — bitwise-identical to per-block ingestion (the engine
+    scans chunks in order; ``coalesce_blocks``) while paying the
+    Python/dispatch overhead once per group. Runs never straddle a
+    publish boundary, so the publish cadence (positions AND count) is
+    exactly the per-block loop's. Each run is split into descending
+    powers of two (7 → 4 + 2 + 1; :func:`group_sizes`), so the group
+    shapes are a fixed set, (W, m·chunk) for m in :func:`group_widths`,
+    and ``start()`` compiles every one of them, in both ingest programs,
+    before the loop serves: no group size compiles while serving. A
+    drain that passes a publish boundary holds the blocks beyond it for
+    the next wakeup, which waits for nothing: under a backlog each
+    publish interval then goes out whole from its boundary, instead of
+    in pieces cut where a drain happened to begin.
   * **transfers run ahead of compute.** Batches are staged through a
     :class:`~repro.runtime.feed.DeviceStager` ``feed_depth`` deep: the
     ``device_put`` of batch i+1 is issued before the ingest of batch i
@@ -68,6 +76,33 @@ from repro.serve.ring import RingPublisher, SnapshotRing
 from repro.service.snapshot import QuerySnapshot
 
 _BLOCK, _PUBLISH, _STOP = "block", "publish", "stop"
+
+
+def group_sizes(n_blocks: int, since_publish: int, publish_every: int,
+                coalesce_max: int) -> list[int]:
+    """The dispatch groups of ``n_blocks`` queued blocks, in queue order.
+
+    A run ends at ``coalesce_max`` blocks or at the next publish boundary,
+    ``publish_every - since_publish`` blocks away, and is split into
+    descending powers of two: every size is one of :func:`group_widths`.
+    """
+    sizes, sp = [], since_publish
+    while n_blocks > 0:
+        run = min(n_blocks, coalesce_max, publish_every - sp)
+        n_blocks -= run
+        sp = (sp + run) % publish_every
+        while run:
+            m = 1 << (run.bit_length() - 1)
+            sizes.append(m)
+            run -= m
+    return sizes
+
+
+def group_widths(publish_every: int, coalesce_max: int) -> tuple[int, ...]:
+    """Every group size :func:`group_sizes` can return: the powers of two
+    up to ``min(coalesce_max, publish_every)``."""
+    cap = min(coalesce_max, publish_every)
+    return tuple(1 << i for i in range(cap.bit_length()))
 
 
 class IngestStats:
@@ -215,6 +250,8 @@ class IngestLoop:
     # -- lifecycle -----------------------------------------------------------
 
     def start(self) -> "IngestLoop":
+        self.runtime.compile_ingest(
+            group_widths(self.publish_every, self.coalesce_max))
         self._thread.start()
         return self
 
@@ -339,28 +376,33 @@ class IngestLoop:
         # first call must not donate the caller-provided initial state
         donate_ok = False
         since_publish = 0
+        carry = []      # drained blocks held back past a publish boundary
         try:
             # version 0-of-this-loop: readers attached before the first
             # block always find a complete (possibly empty) snapshot
             self._publish()
             while True:
-                with span("ingest.wait"):
-                    item = self._queue.get()
-                if item[0] != _BLOCK:
-                    kind, payload = item
-                    if kind == _STOP:
-                        break
-                    since_publish = 0
-                    donate_ok = False
-                    payload.resolve(self._publish())
-                    continue
+                if carry:
+                    # held-back blocks go out now, with whatever was queued
+                    # meanwhile: nothing to wait for
+                    payloads, carry, ctl = carry, [], None
+                else:
+                    with span("ingest.wait"):
+                        item = self._queue.get()
+                    if item[0] != _BLOCK:
+                        kind, payload = item
+                        if kind == _STOP:
+                            break
+                        since_publish = 0
+                        donate_ok = False
+                        payload.resolve(self._publish())
+                        continue
+                    payloads, ctl = [item[1]], None
 
                 # drain every consecutively queued block; a control item
                 # ends the drain (blocks batched here all PRECEDE it in
                 # queue order, so ingest-then-resolve keeps publish_now's
                 # "after everything submitted so far" contract)
-                payloads = [item[1]]
-                ctl = None
                 while ctl is None:
                     try:
                         nxt = self._queue.get_nowait()
@@ -371,19 +413,24 @@ class IngestLoop:
                     else:
                         ctl = nxt
 
+                # a drain that passes a publish boundary holds the blocks
+                # beyond it for the next wakeup, which takes them with what
+                # was queued meanwhile: under a backlog each interval then
+                # goes out whole from its boundary, in the fewest groups,
+                # wherever the drains happen to start
+                n = len(payloads)
+                tail = (since_publish + n) % self.publish_every
+                if ctl is None and 0 < tail < n:
+                    payloads, carry = payloads[:n - tail], payloads[n - tail:]
+
                 # pre-plan coalesce groups: capped at coalesce_max AND at
                 # the distance to the next publish boundary, so publish
                 # positions and counts are identical to the per-block loop
-                groups, i, sp = [], 0, since_publish
-                while i < len(payloads):
-                    cap = max(1, min(self.coalesce_max,
-                                     self.publish_every - sp))
-                    g = payloads[i:i + cap]
-                    groups.append(g)
-                    i += len(g)
-                    sp += len(g)
-                    if sp >= self.publish_every:
-                        sp = 0
+                groups, i = [], 0
+                for m in group_sizes(len(payloads), since_publish,
+                                     self.publish_every, self.coalesce_max):
+                    groups.append(payloads[i:i + m])
+                    i += m
 
                 # stage ahead (async device_put), then dispatch each
                 # group's single coalesced ingest; take() → top_up() →

@@ -86,3 +86,27 @@ def test_bench_run_exits_nonzero_when_a_phase_fails(monkeypatch):
     with pytest.raises(SystemExit) as exit_:
         run.main()
     assert exit_.value.code == 1
+
+
+def test_bench_serve_runs_under_the_static_plan(tmp_path):
+    """The serving bench's child phase, at tiny sizes and under the static
+    plan: it runs to its record, every phase bitwise equal to the
+    reference, with the group width resolved to publish_every."""
+    import json
+
+    from repro.launch import bench_serve
+    from repro.plan import static_plan, use_plan
+
+    out = tmp_path / "BENCH_serve.json"
+    with use_plan(static_plan()):
+        rc = bench_serve.main([
+            "--kernels", "jnp", "--k", "64", "--lanes", "2", "--chunk",
+            "128", "--depth", "2", "--blocks", "12", "--layers", "1",
+            "--readers", "1", "--qps", "20", "--pipeline-blocks", "8",
+            "--out", str(out)])
+        every = static_plan().publish_every
+    assert rc == 0
+    record = json.loads(out.read_text())
+    assert record["config"]["coalesce_max"] == every
+    assert record["summary"]["all_equivalent"]
+    assert record["impls"]["jnp"]["pipeline"]["equivalent"]

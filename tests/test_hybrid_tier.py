@@ -83,11 +83,20 @@ pool = stream.Pool(ids, rt.workers * CHUNK)
 blocks = [pool.block_at(j) for j in range(13)]
 cfg = ServeConfig(runtime=rt.config, publish_every=3, ring_depth=4,
                   lazy_publish=False, flight_recorder=False)
+compiled = []
+def on_compile(event, secs, fun_name="", **kw):
+    if event == "/jax/core/compile/backend_compile_duration":
+        compiled.append(fun_name)
 with ServingTier(cfg, runtime=rt, registry=registry,
                  tracer=tracer) as tier:
+    # start() has compiled every group shape; what compiles from here on
+    # is the publish's programs alone
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
     for b in blocks:
         tier.submit(b)
     snap = tier.drain(timeout=120)
+out["compiled_while_serving"] = list(compiled)
+out["groups"] = registry.histogram("serve.ingest.coalesce_blocks").describe()
 out["reduction"] = rt.engine.config.reduction
 out["snapshot_devices"] = sorted({{d.id for a in (*snap.summary, snap.n)
                                   for d in a.devices()}})
@@ -155,6 +164,17 @@ def test_exchange_launched_inside_its_span_inside_publish(report):
             assert (span, parent) == ("ingest.exchange", "ingest.publish")
         else:
             assert span == "ingest.publish"
+
+
+def test_four_shard_tier_compiles_no_ingest_while_serving(report):
+    """The mesh tier's start() compiles the sharded ingest program for
+    every group shape (widths 1 and 2 at publish_every 3): serving the 13
+    blocks compiles none, whatever groups the drains make."""
+    assert report["groups"]["sum"] == 13
+    assert report["groups"]["max"] <= 2
+    assert not [n for n in report["compiled_while_serving"]
+                if "ingest" in n], report["compiled_while_serving"]
+    assert report["compiled_while_serving"]     # the publish's programs
 
 
 def test_exchanges_counted_once_per_publish_on_four_shards(report):

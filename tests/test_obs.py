@@ -504,7 +504,11 @@ def test_tier_describe_exports_metrics_and_health():
     assert d["metrics"]["serve.read.point_s"]["count"] == 1
     assert d["metrics"]["serve.read.top_s"]["count"] == 1
     assert d["metrics"]["serve.read.kmaj_s"]["count"] == 1
-    assert d["metrics"]["serve.ingest.step_s"]["count"] == 4
+    # one step a dispatch: the 4 blocks went in groups of at most 2 (the
+    # publish_every the width defaults to)
+    groups = d["metrics"]["serve.ingest.coalesce_blocks"]
+    assert d["metrics"]["serve.ingest.step_s"]["count"] == groups["count"]
+    assert groups["sum"] == 4 and groups["max"] <= 2
     assert d["blocks_ingested"] == 4
     assert d["health"]["version"] == d["latest_version"]
     assert health["k_majority"] == 8

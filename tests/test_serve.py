@@ -31,6 +31,7 @@ from repro.engine import EngineConfig
 from repro.runtime import RuntimeConfig, StreamRuntime, host_blocks
 from repro.serve import (IngestLoop, ServeConfig, ServeFrontend,
                          ServingTier, SnapshotRing, StaleSnapshotError)
+from repro.serve.ingest import group_sizes, group_widths
 
 IMPLS = ((os.environ["REPRO_TEST_KERNEL"],)
          if os.environ.get("REPRO_TEST_KERNEL")
@@ -352,11 +353,18 @@ def test_choose_publish_cadence_from_probe_rows():
 
 @pytest.mark.kernel_matrix
 @pytest.mark.parametrize("impl", IMPLS)
-@pytest.mark.parametrize("coalesce", (1, 4, 64))
-def test_tier_coalesced_bitwise_identical(impl, coalesce):
+@pytest.mark.parametrize("coalesce", (1, 4, 64, None))
+@pytest.mark.parametrize("publish_every, queued", ((3, False), (4, True)))
+def test_tier_coalesced_bitwise_identical(impl, coalesce, publish_every,
+                                          queued):
     """Coalescing changes how many blocks share one dispatch, never the
-    sketch (bitwise vs sync per-block ingest) nor the publish cadence —
-    64 exceeds the queue depth, i.e. every drain coalesces maximally."""
+    sketch (bitwise vs sync per-block ingest) nor the publish cadence;
+    64 exceeds the queue depth, and None coalesces up to the publish
+    boundary. Submitted live (publish_every 3), the drains race the
+    submitter and the groups fall where they may. Queued before the loop
+    starts (publish_every 4), the first wakeup drains all 7 blocks: a run
+    of 4 to the publish boundary, and the 3 past it (held for the next
+    wakeup) as 2 + 1, every group size there is."""
     rt = _runtime(impl)
     blocks = _blocks(rt, 7)
 
@@ -365,17 +373,138 @@ def test_tier_coalesced_bitwise_identical(impl, coalesce):
         state = rt.ingest(state, host_blocks(b, rt.workers, CHUNK))
     ref = rt.snapshot(state)
 
-    cfg = _config(impl, publish_every=3, coalesce_max=coalesce)
-    with ServingTier(cfg, runtime=rt) as tier:
+    cfg = _config(impl, publish_every=publish_every, coalesce_max=coalesce)
+    tier = ServingTier(cfg, runtime=rt)
+    if queued:
         for b in blocks:
             tier.submit(b)
+    with tier:
+        if not queued:
+            for b in blocks:
+                tier.submit(b)
         snap = tier.drain()
         stats = tier.stats.describe()
+        groups = tier.registry.histogram(
+            "serve.ingest.coalesce_blocks").describe()
     _summaries_equal(ref, snap)
     assert stats["blocks_ingested"] == 7
-    # initial + after blocks 3 and 6 + the drain publish — identical to
-    # the per-block loop regardless of how wakeups batched the queue
+    # the per-block loop's publishes however the groups were cut: the
+    # initial one, one at each boundary, and the drain publish
+    assert stats["publishes"] == 2 + 7 // publish_every
+    assert groups["sum"] == 7
+    if queued:
+        want = [1] * 7 if coalesce == 1 else [4, 2, 1]
+        assert (groups["count"], groups["max"]) == (len(want), max(want))
+    else:
+        assert groups["max"] in group_widths(publish_every,
+                                             coalesce or publish_every)
+
+
+@pytest.mark.parametrize("every", (3, 6, 8))
+@pytest.mark.parametrize("backlog", range(1, 10))
+def test_group_plan_keeps_the_per_block_publishes(backlog, every):
+    """For a backlog of 1 to queue_depth + 1 (8 + 1) blocks, from every
+    position in a publish interval and at every width: groups are powers
+    of two up to the width, none crosses a publish boundary, and the
+    groups end at each position where the per-block loop publishes."""
+    for coalesce in (1, 2, 3, 4, 8, 64):
+        widths = group_widths(every, coalesce)
+        assert widths == tuple(m for m in (1, 2, 4, 8)
+                               if m <= min(coalesce, every))
+        for since in range(every):
+            sizes = group_sizes(backlog, since, every, coalesce)
+            assert sum(sizes) == backlog
+            assert set(sizes) <= set(widths)
+            ends, sp, pos = set(), since, 0
+            for m in sizes:
+                assert sp + m <= every        # never crosses a boundary
+                sp = (sp + m) % every
+                pos += m
+                ends.add(pos)
+            per_block = {j for j in range(1, backlog + 1)
+                         if (since + j) % every == 0}
+            assert per_block <= ends
+
+
+def test_blocks_past_a_boundary_wait_to_go_out_whole():
+    """A drain that passes a publish boundary holds the blocks beyond it
+    for the next wakeup, which takes them with what was queued meanwhile:
+    5 queued blocks go out as 4 (then the publish) and, with 3 more queued
+    while that group runs, as 1 + 3 = 4 from the boundary, not 1 and 2 + 1
+    (the per-block loop's publish count; bitwise as per-block ingest)."""
+    rt = _runtime()
+    blocks = _blocks(rt, 8)
+    state = rt.init()
+    for b in blocks:
+        state = rt.ingest(state, host_blocks(b, rt.workers, CHUNK))
+    ref = rt.snapshot(state)
+
+    tier = ServingTier(_config(publish_every=4, queue_depth=16), runtime=rt)
+    running, release = threading.Event(), threading.Event()
+    plain = rt._ingest_blocks_fn
+
+    def first_group(state, block):     # the loop's first dispatch waits
+        running.set()
+        release.wait(60)
+        return plain(state, block)
+
+    rt._ingest_blocks_fn = first_group
+    for b in blocks[:5]:
+        tier.submit(b)
+    with tier:
+        assert running.wait(60)
+        for b in blocks[5:]:
+            tier.submit(b)
+        release.set()
+        snap = tier.drain(timeout=60)
+        stats = tier.stats.describe()
+        groups = tier.registry.histogram(
+            "serve.ingest.coalesce_blocks").describe()
+    _summaries_equal(ref, snap)
+    # initial + after blocks 4 and 8 (the drain publishes at 8 too)
     assert stats["publishes"] == 4
+    assert (groups["count"], groups["sum"], groups["min"]) == (2, 8, 4)
+
+
+def test_serving_every_group_size_compiles_nothing_after_start():
+    """start() compiles every group shape in both ingest programs; a
+    burst served afterwards in groups of 4, 2 and 1 compiles nothing."""
+    import jax.monitoring
+
+    rt = _runtime()
+    tier = ServingTier(_config(publish_every=4), runtime=rt)
+    # hold the loop inside its first publish, so that the burst below is
+    # queued whole before the loop's next wakeup
+    held, release = threading.Event(), threading.Event()
+    publish = tier.ring.publish
+
+    def held_publish(snap):
+        held.set()
+        release.wait(60)
+        return publish(snap)
+
+    tier.ring.publish = held_publish
+    compiles = []
+
+    def on_duration(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    with tier:
+        assert held.wait(60)
+        tier.ring.publish = publish
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            for b in _blocks(rt, 7):
+                tier.submit(b)
+            release.set()
+            tier.drain(timeout=60)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_duration)
+        groups = tier.registry.histogram(
+            "serve.ingest.coalesce_blocks").describe()
+    assert (groups["count"], groups["sum"], groups["max"]) == (3, 7, 4)
+    assert compiles == []
 
 
 @pytest.mark.kernel_matrix
@@ -487,23 +616,21 @@ def test_plan_roundtrips_pipeline_knobs():
 
     from repro.plan import ExecutionPlan, active_plan
 
-    plan = dataclasses.replace(active_plan(), coalesce_max=4,
-                               feed_depth=3, lazy_publish=True)
+    plan = dataclasses.replace(active_plan(), feed_depth=3,
+                               lazy_publish=True)
     d = plan.to_json()
-    assert (d["coalesce_max"], d["feed_depth"], d["lazy_publish"]) == \
-        (4, 3, True)
+    assert (d["feed_depth"], d["lazy_publish"]) == (3, True)
+    assert "coalesce_max" not in d
     back = ExecutionPlan.from_json(d)
-    assert (back.coalesce_max, back.feed_depth, back.lazy_publish) == \
-        (4, 3, True)
+    assert (back.feed_depth, back.lazy_publish) == (3, True)
     # plans cached before the async pipeline existed load with the
-    # legacy behavior: per-block dispatch, double-buffer, eager publish
+    # legacy behavior: double-buffer, eager publish
     legacy = {k: v for k, v in d.items()
-              if k not in ("coalesce_max", "feed_depth", "lazy_publish")}
+              if k not in ("feed_depth", "lazy_publish")}
     old = ExecutionPlan.from_json(json.loads(json.dumps(legacy)))
-    assert (old.coalesce_max, old.feed_depth, old.lazy_publish) == \
-        (1, 2, False)
-    with pytest.raises(ValueError):
-        dataclasses.replace(plan, coalesce_max=0)
+    assert (old.feed_depth, old.lazy_publish) == (2, False)
+    # a coalesce width cached by an older tuner is ignored
+    assert ExecutionPlan.from_json({**d, "coalesce_max": 1}) == back
     with pytest.raises(ValueError):
         dataclasses.replace(plan, feed_depth=0)
 
@@ -513,11 +640,10 @@ def test_serve_config_resolves_pipeline_knobs_through_plan():
 
     from repro.plan import active_plan, use_plan
 
-    plan = dataclasses.replace(active_plan(), coalesce_max=6,
+    plan = dataclasses.replace(active_plan(), publish_every=6,
                                feed_depth=3, lazy_publish=True)
     with use_plan(plan):
         cfg = _config(coalesce_max=None, lazy_publish=None)
-        assert cfg.resolved_coalesce_max() == 6
         assert cfg.resolved_lazy_publish() is True
         assert cfg.runtime.resolved_feed_depth() == 3
         # explicit knobs always beat the plan — including explicit False
@@ -526,30 +652,30 @@ def test_serve_config_resolves_pipeline_knobs_through_plan():
         assert pinned.resolved_lazy_publish() is False
         rcfg = dataclasses.replace(cfg.runtime, feed_depth=5)
         assert rcfg.resolved_feed_depth() == 5
+        # the width is not planned: groups run up to the publish
+        # boundary, at the resolved publish_every
+        assert cfg.resolved_coalesce_max() == 2
+        assert _config(coalesce_max=None,
+                       publish_every=None).resolved_coalesce_max() == 6
 
 
 def test_choose_pipeline_from_probe_rows():
     from repro.launch.tune import _choose_pipeline
 
     rows = [
-        {"op": "pipeline", "knob": "coalesce", "m": 1, "block_s": 1.00},
-        {"op": "pipeline", "knob": "coalesce", "m": 2, "block_s": 0.62},
-        {"op": "pipeline", "knob": "coalesce", "m": 4, "block_s": 0.60},
-        {"op": "pipeline", "knob": "coalesce", "m": 8, "block_s": 0.61},
         {"op": "pipeline", "knob": "feed", "depth": 1, "block_s": 1.00},
         {"op": "pipeline", "knob": "feed", "depth": 2, "block_s": 0.80},
         {"op": "pipeline", "knob": "feed", "depth": 4, "block_s": 0.79},
         {"op": "pipeline", "knob": "publish", "step_s": 1.0,
          "eager_s": 0.2},
     ]
-    co, fe, lazy = _choose_pipeline(rows)
-    assert co == 4      # m=2 sits outside the 2% slack of the 0.60 best
+    fe, lazy = _choose_pipeline(rows)
     assert fe == 2      # depth 2 is within slack of depth 4 — take less
     assert lazy is True  # eager publish costs 20% of a step: defer it
-    assert _choose_pipeline([]) == (1, 2, False)
+    assert _choose_pipeline([]) == (2, False)
     rows[-1] = {"op": "pipeline", "knob": "publish", "step_s": 1.0,
                 "eager_s": 0.01}
-    assert _choose_pipeline(rows)[2] is False   # publish already cheap
+    assert _choose_pipeline(rows)[1] is False   # publish already cheap
 
 
 # ---------------------------------------------------------------------------
